@@ -10,6 +10,10 @@ def main() -> None:
     args = ap.parse_args()
     skip = set(filter(None, args.skip.split(",")))
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         ablation_removal,
         kernel_bench,

@@ -91,10 +91,11 @@ def run_arm(cfg, params, trace, *, tp: int | None, num_slots: int = 4):
     seconds, live per-device pool bytes)."""
     import jax
 
+    from repro.launch.mesh import make_mesh
     from repro.serve import cache as C
     from repro.serve.scheduler import ServeSession, scheduler_compile_stats
 
-    mesh = None if tp is None else jax.make_mesh((tp,), ("model",))
+    mesh = None if tp is None else make_mesh((tp,), ("model",))
 
     def serve():
         sess = ServeSession(

@@ -8,9 +8,10 @@ host side is hidden (PR 4).  This kernel instead walks each request's block
 table and streams K/V blocks from the pool straight into VMEM tiles:
 
 * grid ``(B, W)`` with the table walk innermost; the block index maps read
-  the scalar-prefetched ``block_table``, so grid step ``(b, w)`` DMAs
-  physical block ``block_table[b, w]`` — the pool is indexed where it
-  lives, and only blocks a request actually holds ever cross HBM->VMEM;
+  a scalar-prefetched fetch table derived from ``block_table``, so grid
+  step ``(b, w)`` DMAs physical block ``block_table[b, w]`` — the pool is
+  indexed where it lives, and only blocks a request actually holds ever
+  cross HBM->VMEM;
 * the new token's K/V (``k_new``/``v_new``, already rotary-embedded at
   ``cur_len``) is fused into the current block's VMEM tile at offset
   ``cur_len % block_size`` before the QK^T — attention never waits on the
@@ -19,11 +20,12 @@ table and streams K/V blocks from the pool straight into VMEM tiles:
 * per-block scores feed a running online softmax (``m``/``l``/``acc``
   scratch carried across the ``w`` walk, flushed at ``w == W - 1``);
 * sentinel table entries (``id >= num_blocks``: unallocated / padding
-  rows) are SKIPPED — ``@pl.when`` drops the tile's compute, and the index
-  map re-maps invalid steps to the row's last valid block so Pallas's
-  consecutive-same-block dedup elides their DMAs too, where the gather
-  path had to clamp, gather garbage, and rely on the kv_len mask.  Rows
-  with no valid block (inactive slots) flush exactly zero.
+  rows) and blocks past ``cur_len`` are SKIPPED — ``@pl.when`` drops the
+  tile's compute, and the fetch table re-maps every such step to the row's
+  last valid block so Pallas's consecutive-same-block dedup elides their
+  DMAs too, where the gather path had to clamp, gather garbage, and rely
+  on the kv_len mask.  Rows with no valid block (inactive slots) flush
+  exactly zero.
 
 Numerics: scores/softmax/AV all accumulate in f32 exactly like
 ``attention_core``; masked in-block tail positions sit at -1e30, so their
@@ -34,10 +36,13 @@ bitwise.  Greedy ARGMAX outputs stay bit-identical across serve traces
 (asserted in tests/test_paged.py); ``ref.py`` is the exact-math oracle the
 property tests difference against.
 
-TPU tiling note: tiles are ``(block_size, Hkv, hd)``; compiled mode wants
-``hd`` a multiple of 128 and ``block_size`` a multiple of the sublane
-count.  Interpret mode (CPU CI, ``REPRO_FORCE_INTERPRET=1``) has no such
-constraint and runs this exact kernel body.
+TPU lowering note: Mosaic index maps may only read scalars from SMEM, so
+the "last valid block at or before ``w``" search runs in the jitted
+wrapper (a cumulative max over the table) and the index map reads one
+entry of its result.  Tiles are ``(block_size, Hkv, hd)``; granite's
+``hd`` 64 / ``block_size`` 16 compiles for v5e in bf16 and f32
+(``tests/test_tpu_compile.py``).  Interpret mode (CPU CI,
+``REPRO_FORCE_INTERPRET=1``) runs this exact kernel body.
 """
 from __future__ import annotations
 
@@ -56,6 +61,7 @@ _NEG = -1e30
 def _kernel(
     tbl_ref,      # (B, W) int32 scalar-prefetch: physical block ids
     len_ref,      # (B,)  int32 scalar-prefetch: new-token positions
+    fetch_ref,    # (B, W) int32 scalar-prefetch: block DMA'd at (b, w)
     q_ref,        # (1, H, hd) this row's query
     kn_ref,       # (1, Hkv, hd) new token K (post-rope)
     vn_ref,       # (1, Hkv, hd) new token V
@@ -164,32 +170,39 @@ def paged_attention_kernel_call(
     assert hd == hd_k and H % n_kv == 0, (q.shape, k_pool.shape)
     W = block_table.shape[1]
 
-    def pool_index(b, w, tbl, lens):
-        # The paged indirection.  A BlockSpec index map always implies a
-        # fetch, so a sentinel entry cannot simply be "skipped" here — the
-        # predicate in the kernel body skips the COMPUTE, and this map
-        # makes the skip real for the DMA too by re-mapping every invalid
-        # step to the row's last valid block (block 0 for all-sentinel
-        # rows): Pallas elides the copy when consecutive grid steps map to
-        # the same block, so sentinel runs issue no extra HBM traffic.
-        row = tbl[b]                                     # (W,) entries
-        js = jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)[:, 0]
-        ok = (row < num_blocks) & (js <= w)
-        j_star = jnp.max(jnp.where(ok, js, -1))
-        entry = row[jnp.maximum(j_star, 0)]
-        return (jnp.where(j_star >= 0, entry, 0), 0, 0, 0)
+    # The paged indirection.  A BlockSpec index map always implies a fetch,
+    # so a skipped step cannot simply be "not fetched" — the predicate in
+    # the kernel body skips the COMPUTE, and this table makes the skip real
+    # for the DMA too by re-mapping every invalid step to the row's last
+    # valid block at or before it (block 0 before a row's first valid
+    # block): Pallas elides the copy when consecutive grid steps map to the
+    # same block, so skipped runs issue no extra HBM traffic.
+    js = jnp.arange(W, dtype=jnp.int32)[None, :]
+    ok = (block_table < num_blocks) & (js * block_size <= cur_len[:, None])
+    last = jax.lax.cummax(jnp.where(ok, js, -1), axis=1)
+    fetch = jnp.where(
+        last >= 0,
+        jnp.take_along_axis(block_table, jnp.maximum(last, 0), axis=1),
+        0,
+    )
+
+    def pool_index(b, w, tbl, lens, fetch):
+        return (fetch[b, w], 0, 0, 0)
+
+    def row_index(b, w, tbl, lens, fetch):
+        return (b, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, W),
         in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, w, tbl, lens: (b, 0, 0)),
-            pl.BlockSpec((1, n_kv, hd), lambda b, w, tbl, lens: (b, 0, 0)),
-            pl.BlockSpec((1, n_kv, hd), lambda b, w, tbl, lens: (b, 0, 0)),
+            pl.BlockSpec((1, H, hd), row_index),
+            pl.BlockSpec((1, n_kv, hd), row_index),
+            pl.BlockSpec((1, n_kv, hd), row_index),
             pl.BlockSpec((1, block_size, n_kv, hd), pool_index),
             pl.BlockSpec((1, block_size, n_kv, hd), pool_index),
         ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, w, tbl, lens: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, hd), row_index),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
@@ -201,9 +214,7 @@ def paged_attention_kernel_call(
     )
     kwargs = {}
     if not interpret:
-        # jax 0.4.x names this TPUCompilerParams; never touched off-TPU
-        params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-        kwargs["compiler_params"] = params_cls(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         )
     return pl.pallas_call(
@@ -212,4 +223,4 @@ def paged_attention_kernel_call(
         out_shape=jax.ShapeDtypeStruct((B, H, hd), jnp.float32),
         interpret=interpret,
         **kwargs,
-    )(block_table, cur_len, q, k_new, v_new, k_pool, v_pool)
+    )(block_table, cur_len, fetch, q, k_new, v_new, k_pool, v_pool)

@@ -129,7 +129,7 @@ def _measure(cfg, shape, mesh, opt_cfg, *, microbatch, frozen_weights=False,
              grad_compression=False):
     """Lower+compile one variant; return (flops, bytes, wire)/device + times."""
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         jfn, args = build_lowerable(cfg, shape, mesh, opt_cfg, microbatch=microbatch,
                                     frozen_weights=frozen_weights,
                                     grad_compression=grad_compression)
@@ -238,7 +238,7 @@ def run_cell(
 
     # 1) production lowering: proves shardability + gives per-device memory
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         jfn, args = build_lowerable(cfg, shape, mesh, opt_cfg,
                                     frozen_weights=frozen_weights,
                                     grad_compression=grad_compression,

@@ -46,6 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.serve.scheduler import (
     ADMISSION_POLICIES,
     ATTN_IMPLS,
@@ -183,6 +185,7 @@ def main(argv=None):
                          "--cache-layout paged; on CPU force devices with "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -236,7 +239,7 @@ def main(argv=None):
                     "(on CPU: XLA_FLAGS="
                     f"--xla_force_host_platform_device_count={args.tp})"
                 )
-            mesh = jax.make_mesh((args.tp,), ("model",))
+            mesh = make_mesh((args.tp,), ("model",))
         tiers = None
         if args.tiers:
             tiers = tuple(t.strip() for t in args.tiers.split(",") if t.strip())

@@ -22,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import SHAPES, get_config, reduced_config
 from repro.core.approx import ApproxConfig
 from repro.data.synthetic import token_batches
-from repro.launch.mesh import batch_axes
+from repro.launch.mesh import batch_axes, make_mesh
 from repro.parallel.sharding import batch_pspecs, param_shardings, prune_pspec
 from repro.train import optim as O
 from repro.train.checkpoint import latest_step, restore_checkpoint, save_checkpoint
@@ -34,10 +34,10 @@ from repro.launch.specs import state_specs
 def make_mesh_from_args(spec: str):
     devs = np.array(jax.devices())
     if spec == "auto":
-        return jax.make_mesh((len(devs), 1), ("data", "model"))
+        return make_mesh((len(devs), 1), ("data", "model"))
     dims = tuple(int(x) for x in spec.split("x"))
     axes = ("pod", "data", "model")[-len(dims):]
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def main(argv=None):
@@ -74,7 +74,7 @@ def main(argv=None):
             state, start = restore_checkpoint(args.ckpt, jax.eval_shape(lambda: state))
             print(f"[attempt {attempt}] resumed at step {start}")
 
-        with mesh:
+        with jax.set_mesh(mesh):
             psh = param_shardings(cfg, state["params"], mesh)
             ssh = {"params": psh, "opt": O.opt_state_shardings(opt, psh, mesh)}
             if "grad_err" in state:

@@ -372,7 +372,7 @@ def paged_decode_attention(
             paged_attention_pallas,
             validate_tp_heads,
         )
-        from repro.parallel.sharding import current_mesh, mesh_axis_size
+        from repro.parallel.sharding import mesh_axis_size
 
         # pre-scatter pool operands on purpose: the kernel fuses the new
         # token in VMEM, so the scatter above only persists it for the
@@ -384,27 +384,25 @@ def paged_decode_attention(
                 qh, kh, vh, kp, vp, bt, cl, block_size=block_size
             )
 
-        mesh = current_mesh()
         tp = mesh_axis_size("model")
-        if mesh is not None and tp > 1:
-            # pallas_call is not partitioned by GSPMD — map it per shard.
-            # Each shard runs the unmodified kernel over its Hkv/tp pool
-            # heads and H/tp query heads (group structure preserved, see
-            # validate_tp_heads); the block table and lengths replicate, so
-            # every shard walks the same host-global table.
-            from jax.experimental.shard_map import shard_map
+        if tp > 1:
+            # pallas_call is not partitioned by GSPMD — map it per shard of
+            # the installed mesh.  Each shard runs the unmodified kernel
+            # over its Hkv/tp pool heads and H/tp query heads (group
+            # structure preserved, see validate_tp_heads); the block table
+            # and lengths replicate, so every shard walks the same
+            # host-global table.
             from jax.sharding import PartitionSpec as P
 
             validate_tp_heads(n_heads, n_kv, tp)
             hspec = P(None, "model", None)
             pspec = P(None, None, "model", None)
-            call = shard_map(
+            call = jax.shard_map(
                 call,
-                mesh=mesh,
                 in_specs=(hspec, hspec, hspec, pspec, pspec,
                           P(None, None), P(None)),
                 out_specs=hspec,
-                check_rep=False,
+                check_vma=False,
             )
         out = call(
             q[:, 0],

@@ -14,7 +14,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.launch.mesh import batch_axes
@@ -30,11 +30,9 @@ __all__ = [
 ]
 
 
-def current_mesh() -> Optional[Mesh]:
-    """The physical mesh installed by ``with mesh:`` (None outside)."""
-    from jax._src.mesh import thread_resources
-
-    m = thread_resources.env.physical_mesh
+def current_mesh() -> Optional[AbstractMesh]:
+    """The mesh installed by ``with jax.set_mesh(mesh):`` (None outside)."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 
 
@@ -63,7 +61,7 @@ def constrain(x: jax.Array, axes: Tuple[Any, ...]) -> jax.Array:
             spec.append(names if len(names) > 1 else names[0])
         else:
             spec.append(None)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(m, P(*spec)))
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 # (regex on path, (dim -> axis name) from the END of the shape)
 # axis names: "fsdp" -> data, "tp" -> model; resolved per-mesh.
@@ -159,7 +157,10 @@ def batch_pspecs(cfg: ModelConfig, mesh: Mesh, kind: str) -> Dict[str, P]:
 
 def prune_pspec(mesh: Mesh, spec: P, shape: Tuple[int, ...]) -> P:
     """Drop per-dim axes whose size does not divide the dim (e.g. batch=1
-    for long_500k): jit in_shardings require exact divisibility."""
+    for long_500k): jit in_shardings require exact divisibility.  Trailing
+    replicated dims are dropped too — the form jit gives its outputs — so
+    an array placed here and a program output of the same layout share one
+    jit cache key (``P(..., "model", None) != P(..., "model")``)."""
     out = []
     for dim, entry in zip(shape, tuple(spec) + (None,) * (len(shape) - len(spec))):
         if entry is None:
@@ -172,6 +173,8 @@ def prune_pspec(mesh: Mesh, spec: P, shape: Tuple[int, ...]) -> P:
             out.append(names if len(names) > 1 else names[0])
         else:
             out.append(None)
+    while out and out[-1] is None:
+        out.pop()
     return P(*out)
 
 

@@ -1511,7 +1511,7 @@ class ServeSession:
         # -- tensor parallelism ----------------------------------------------
         # Shard params by the param_pspec rules (Megatron column/row split)
         # and the paged pool along the KV-head dim (cache_pspecs paged
-        # layout); all program dispatches then run under `with mesh:` (see
+        # layout); all program dispatches then run under ``jax.set_mesh`` (see
         # _mesh_ctx) so constrain() sees the mesh at trace time.
         self.mesh = mesh
         self.tp_axis = tp_axis
@@ -2852,12 +2852,14 @@ class ServeSession:
             self.stats.wall_s += time.perf_counter() - t0
 
     def _mesh_ctx(self):
-        """Every device dispatch runs under ``with mesh:`` when the session
-        is tensor-parallel — ``constrain()`` and GSPMD read the mesh from the
-        thread-resource env at trace time, and the mesh context is part of
-        the jit cache key, so warmup and serving must install the SAME
-        context for the zero-recompile contract to hold."""
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        """Every device dispatch runs under ``jax.set_mesh(mesh)`` when the
+        session is tensor-parallel — ``constrain()`` and the Pallas
+        ``shard_map`` read the mesh at trace time, and the mesh context is
+        part of the jit cache key, so warmup and serving must install the
+        SAME context for the zero-recompile contract to hold."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
 
     # -- quality tiers: load shedding and per-rung dispatch -------------------
 

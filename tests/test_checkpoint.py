@@ -61,8 +61,11 @@ def test_elastic_restore_different_sharding(tmp_path):
     d = str(tmp_path / "ckpt")
     tree = _tree()
     save_checkpoint(d, 9, tree)
-    mesh = jax.make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
 
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
     restored, step = restore_checkpoint(d, tree, shardings=sh)

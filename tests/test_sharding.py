@@ -11,6 +11,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import param_pspec, prune_pspec
 
 
@@ -51,7 +52,7 @@ def test_param_rules_moe_ep_vs_tp():
 
 
 def test_prune_pspec_divisibility():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     assert prune_pspec(mesh, P("data"), (7,)) == P(None) or prune_pspec(
         mesh, P("data"), (7,)
     ) == P("data")  # axis size 1 always divides
@@ -71,15 +72,16 @@ def test_small_mesh_dryrun_subprocess(tmp_path):
         from repro.configs import get_config, reduced_config, SHAPES
         from repro.core.approx import ApproxConfig
         from repro.launch.dryrun import build_lowerable
+        from repro.launch.mesh import make_mesh
         from repro.train import optim as O
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(
             reduced_config(get_config("granite-3-2b")),
             approx=ApproxConfig(mode="lowrank"), q_chunk=32,
         )
         shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64, global_batch=4)
-        with mesh:
+        with jax.set_mesh(mesh):
             jfn, args = build_lowerable(cfg, shape, mesh, O.OptConfig(), microbatch=1)
             compiled = jfn.lower(*args).compile()
         mem = compiled.memory_analysis()
@@ -112,6 +114,7 @@ def test_constrain_and_cache_pspecs_subprocess():
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config, reduced_config
+        from repro.launch.mesh import make_mesh
         from repro.models.transformer import init_paged_cache
         from repro.parallel.sharding import cache_pspecs, constrain
 
@@ -126,7 +129,7 @@ def test_constrain_and_cache_pspecs_subprocess():
         out["no_mesh"] = spec_of(f(jnp.zeros((8, 4, 8)))) is None
 
         # pure-TP mesh: no pod/data axes -> "batch" drops; "model" applies
-        with jax.make_mesh((4,), ("model",)):
+        with jax.set_mesh(make_mesh((4,), ("model",))):
             y = f(jnp.zeros((8, 4, 8)))
             out["tp_only"] = spec_of(y) == (None, None, "model")
             # divisibility fallback: 6 % 4 != 0 -> trailing axis dropped
@@ -135,21 +138,22 @@ def test_constrain_and_cache_pspecs_subprocess():
             out["indivisible"] = spec_of(z) in ((), (None, None, None))
 
         # pod x data x model mesh: "batch" -> ("pod", "data")
-        with jax.make_mesh((2, 2, 2), ("pod", "data", "model")):
+        with jax.set_mesh(make_mesh((2, 2, 2), ("pod", "data", "model"))):
             y = f(jnp.zeros((8, 4, 8)))
             out["batch_pair"] = spec_of(y) == (("pod", "data"), None, "model")
 
         # paged cache_pspecs: k/v shard dim 3 (Hkv) over "model"; block
-        # tables / metadata and the block dim stay replicated
+        # tables / metadata and the block dim stay replicated (trailing
+        # replicated dims normalize away, as in jit outputs)
         cfg = dataclasses.replace(
             reduced_config(get_config("granite-3-2b")),
             num_layers=2, num_heads=4, num_kv_heads=4, head_dim=16,
         )
         cache = init_paged_cache(cfg, num_blocks=16, block_size=8)
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = make_mesh((4,), ("model",))
         sh = cache_pspecs(cfg, mesh, cache, layout="paged")
-        out["paged_kv"] = tuple(sh["k"].spec) == (None, None, None, "model", None)
-        out["paged_v"] = tuple(sh["v"].spec) == (None, None, None, "model", None)
+        out["paged_kv"] = tuple(sh["k"].spec) == (None, None, None, "model")
+        out["paged_v"] = tuple(sh["v"].spec) == (None, None, None, "model")
 
         # Hkv not divisible by tp -> replicate rather than mis-shard
         cfg3 = dataclasses.replace(cfg, num_heads=3, num_kv_heads=3)
