@@ -118,8 +118,11 @@ def approx_matmul_kernel_call(
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         )
+    # the launcher's own name, fixed: profiles and the HLO name the custom
+    # call after it, whatever jitted code calls this launcher
     return pl.pallas_call(
         kernel,
+        name="approx_matmul_kernel_call",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),

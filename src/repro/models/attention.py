@@ -6,6 +6,8 @@ K/V are never head-repeated: scores are computed with grouped einsums
 ``n_kv`` heads — this is what makes decode_32k x batch 128 fit.
 
 All projections route through ``layers.dense`` (approximate-multiplier aware).
+The attention entry points run under the ``attention`` name scope and their
+KV cache writes under ``kv_write``; the projections inside carry ``dense``.
 The score/AV einsums stay exact float — the paper approximates the MAC arrays
 of conv/fc layers, and projection matmuls are the analogous LM hot spots;
 see DESIGN.md §5.
@@ -172,6 +174,7 @@ def attention_core(
     return unpad(ob.swapaxes(0, 1).reshape(B, Sq, H, hd))
 
 
+@jax.named_scope("attention")
 def self_attention(
     x: jax.Array,                 # (B, S, d)
     p: AttnParams,
@@ -219,6 +222,7 @@ def self_attention(
     return out, (k, v)
 
 
+@jax.named_scope("kv_write")
 def seed_kv_cache(
     k_cache: jax.Array,           # (B, Smax, Hkv, hd)
     v_cache: jax.Array,
@@ -263,6 +267,7 @@ def _decode_qkv(
     return q, k, v
 
 
+@jax.named_scope("attention")
 def decode_attention(
     x: jax.Array,                 # (B, 1, d)
     p: AttnParams,
@@ -284,14 +289,16 @@ def decode_attention(
     )
     hd = q.shape[3]
     # scatter new kv at cur_len (per-batch dynamic index)
-    b_idx = jnp.arange(B)
-    k_cache = k_cache.at[b_idx, cur_len].set(k[:, 0].astype(k_cache.dtype))
-    v_cache = v_cache.at[b_idx, cur_len].set(v[:, 0].astype(v_cache.dtype))
+    with jax.named_scope("kv_write"):
+        b_idx = jnp.arange(B)
+        k_cache = k_cache.at[b_idx, cur_len].set(k[:, 0].astype(k_cache.dtype))
+        v_cache = v_cache.at[b_idx, cur_len].set(v[:, 0].astype(v_cache.dtype))
     out = attention_core(q, k_cache, v_cache, causal=False, kv_len=cur_len + 1, q_chunk=1)
     out = L.dense(out.reshape(B, 1, n_heads * hd), p.wo, cfg)
     return out, (k_cache, v_cache)
 
 
+@jax.named_scope("attention")
 def paged_decode_attention(
     x: jax.Array,                 # (B, 1, d)
     p: AttnParams,
@@ -359,14 +366,15 @@ def paged_decode_attention(
     hd = q.shape[3]
     num_blocks = k_blocks.shape[0]
     W = block_table.shape[1]
-    blk = cur_len // block_size
-    off = cur_len % block_size
-    phys = jnp.take_along_axis(
-        block_table, jnp.minimum(blk, W - 1)[:, None], axis=1
-    )[:, 0]
-    phys = jnp.where(blk < W, phys, num_blocks)      # past-table -> dropped
-    new_k = k_blocks.at[phys, off].set(k[:, 0].astype(k_blocks.dtype))
-    new_v = v_blocks.at[phys, off].set(v[:, 0].astype(v_blocks.dtype))
+    with jax.named_scope("kv_write"):
+        blk = cur_len // block_size
+        off = cur_len % block_size
+        phys = jnp.take_along_axis(
+            block_table, jnp.minimum(blk, W - 1)[:, None], axis=1
+        )[:, 0]
+        phys = jnp.where(blk < W, phys, num_blocks)  # past-table -> dropped
+        new_k = k_blocks.at[phys, off].set(k[:, 0].astype(k_blocks.dtype))
+        new_v = v_blocks.at[phys, off].set(v[:, 0].astype(v_blocks.dtype))
     if attn_impl == "pallas":
         from repro.kernels.paged_attention import (
             paged_attention_pallas,
@@ -418,6 +426,7 @@ def paged_decode_attention(
     return out, (new_k, new_v)
 
 
+@jax.named_scope("attention")
 def paged_verify_attention(
     x: jax.Array,                 # (B, S, d) — S = draft_k + 1 verify positions
     p: AttnParams,
@@ -467,12 +476,13 @@ def paged_verify_attention(
         q, k = L.apply_rope(q, k, pos, theta=rope_theta)
     num_blocks = k_blocks.shape[0]
     W = block_table.shape[1]
-    blk = pos // block_size                      # (B, S)
-    off = pos % block_size
-    phys = jnp.take_along_axis(block_table, jnp.minimum(blk, W - 1), axis=1)
-    phys = jnp.where(blk < W, phys, num_blocks)  # past-table -> dropped
-    new_k = k_blocks.at[phys, off].set(k.astype(k_blocks.dtype))
-    new_v = v_blocks.at[phys, off].set(v.astype(v_blocks.dtype))
+    with jax.named_scope("kv_write"):
+        blk = pos // block_size                  # (B, S)
+        off = pos % block_size
+        phys = jnp.take_along_axis(block_table, jnp.minimum(blk, W - 1), axis=1)
+        phys = jnp.where(blk < W, phys, num_blocks)  # past-table -> dropped
+        new_k = k_blocks.at[phys, off].set(k.astype(k_blocks.dtype))
+        new_v = v_blocks.at[phys, off].set(v.astype(v_blocks.dtype))
     kg = new_k[block_table].reshape(B, W * block_size, n_kv, hd)
     vg = new_v[block_table].reshape(B, W * block_size, n_kv, hd)
     outs = [

@@ -36,11 +36,14 @@ def init_dense(key, d_in: int, d_out: int, scale: float = 1.0) -> jax.Array:
     return truncated_normal_init(key, (d_in, d_out), scale)
 
 
+@jax.named_scope("dense")
 def dense(x: jax.Array, w, cfg: ApproxConfig) -> jax.Array:
     """x (..., K) @ w (K, N) under the configured multiplier semantics:
     ``cfg.mode`` selects float, exact-quant, LUT, low-rank or the Pallas
     kernel (the serving engine's ``exact``/``approx`` execution modes resolve
-    to these). ``w`` may be a frozen ``QWeight`` (serving path)."""
+    to these). ``w`` may be a frozen ``QWeight`` (serving path). Runs under
+    the ``dense`` name scope, so every operation of a projection carries it
+    in its HLO ``op_name``, whatever the mode."""
     if isinstance(w, QWeight):
         return approx_dense(x, w, cfg).astype(x.dtype)
     if cfg.mode == "float":
@@ -48,6 +51,7 @@ def dense(x: jax.Array, w, cfg: ApproxConfig) -> jax.Array:
     return approx_dense(x, w, cfg).astype(x.dtype)
 
 
+@jax.named_scope("norm")
 def rms_norm(x: jax.Array, gamma: jax.Array, eps: float = 1e-6) -> jax.Array:
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * gamma.astype(x.dtype)

@@ -12,6 +12,11 @@ independent — essential for 512-device dry-run compiles of 60+-layer models.
 
 Caches are pytrees stacked the same way; ``decode_step`` scans over
 (params, cache) jointly.
+
+Name scopes label each layer's operations in the compiled HLO (``op_name``)
+and so in device profiles: ``embed``, ``norm``, ``attention``, ``kv_write``,
+``dense`` and ``mlp`` (the FFN's gate and activation); an operation belongs to
+the innermost scope on its path, and what XLA adds (the scan's copies) to none.
 """
 from __future__ import annotations
 
@@ -65,6 +70,7 @@ def _init_ffn(key, d: int, ff: int) -> FFNParams:
     )
 
 
+@jax.named_scope("mlp")
 def _ffn(x, p: FFNParams, cfg: ApproxConfig, fuse_gate_up: bool = False):
     # Megatron split: gate/up are column-parallel, down is row-parallel —
     # pinning the hidden activation head-sharded over "model" keeps the whole
@@ -81,6 +87,15 @@ def _ffn(x, p: FFNParams, cfg: ApproxConfig, fuse_gate_up: bool = False):
         h = jax.nn.silu(L.dense(x, p.w_gate, cfg)) * L.dense(x, p.w_up, cfg)
     h = constrain(h, ("batch",) + (None,) * (h.ndim - 2) + ("model",))
     return L.dense(h, p.w_down, cfg)
+
+
+@jax.named_scope("embed")
+def _embed(cfg: ModelConfig, params, batch) -> jax.Array:
+    """The token embedding lookup (or the given embeddings) in the model
+    dtype."""
+    if cfg.embed_input:
+        return params["embed"][batch["tokens"]].astype(jnp.dtype(cfg.dtype))
+    return batch["embeddings"].astype(jnp.dtype(cfg.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -296,10 +311,7 @@ def forward(
     from repro.parallel.sharding import constrain
 
     dtype = jnp.dtype(cfg.dtype)
-    if cfg.embed_input:
-        x = params["embed"][batch["tokens"]].astype(dtype)
-    else:
-        x = batch["embeddings"].astype(dtype)
+    x = _embed(cfg, params, batch)
     if cfg.pos_embedding == "sinusoidal":
         x = x + L.sinusoidal_positions(x.shape[1], cfg.d_model).astype(dtype)
     x = constrain(x, ("batch", None, None))
@@ -399,10 +411,7 @@ def decode_step(
     """One-token decode. batch: {"tokens": (B,1)} or {"embeddings": (B,1,d)}.
     Returns (logits (B,1,V), new_cache)."""
     dtype = jnp.dtype(cfg.dtype)
-    if cfg.embed_input:
-        x = params["embed"][batch["tokens"]].astype(dtype)
-    else:
-        x = batch["embeddings"].astype(dtype)
+    x = _embed(cfg, params, batch)
     if cfg.pos_embedding == "sinusoidal":
         x = x + L.sinusoidal_at(cur_len, cfg.d_model)[:, None, :].astype(dtype)
 
@@ -516,10 +525,7 @@ def paged_decode_step(
     from repro.parallel.sharding import constrain
 
     dtype = jnp.dtype(cfg.dtype)
-    if cfg.embed_input:
-        x = params["embed"][batch["tokens"]].astype(dtype)
-    else:
-        x = batch["embeddings"].astype(dtype)
+    x = _embed(cfg, params, batch)
     if cfg.pos_embedding == "sinusoidal":
         x = x + L.sinusoidal_at(cur_len, cfg.d_model)[:, None, :].astype(dtype)
     # TP: the residual stream stays replicated over "model" — each layer's
@@ -586,10 +592,7 @@ def paged_verify_step(
     from repro.parallel.sharding import constrain
 
     dtype = jnp.dtype(cfg.dtype)
-    if cfg.embed_input:
-        x = params["embed"][batch["tokens"]].astype(dtype)
-    else:
-        x = batch["embeddings"].astype(dtype)
+    x = _embed(cfg, params, batch)
     S = x.shape[1]
     if cfg.pos_embedding == "sinusoidal":
         pos = cur_len[:, None] + jnp.arange(S, dtype=cur_len.dtype)[None, :]

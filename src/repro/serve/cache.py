@@ -137,6 +137,7 @@ def evict_slot(cache: Any, slot: jax.Array) -> Any:
     )
 
 
+@jax.named_scope("kv_write")
 def scatter_rows(
     full: jax.Array,
     part: jax.Array,
@@ -228,6 +229,7 @@ def insert_prefill_kv(cache: Any, kvs: Tuple[jax.Array, jax.Array], slot: jax.Ar
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("kv_write")
 def scatter_prompt_blocks(
     cache: Any,
     kvs: Tuple[jax.Array, jax.Array],

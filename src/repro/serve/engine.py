@@ -194,6 +194,7 @@ class GenerationState(NamedTuple):
     rng: jax.Array             # PRNG key threaded through sampling
 
 
+@jax.named_scope("sample")
 def select_token(logits: jax.Array, sampling: SamplingConfig, rng) -> jax.Array:
     """(B, V) logits -> (B,) int32 next tokens under the static sampling
     config (python branches are resolved at trace time)."""

@@ -168,6 +168,10 @@ CACHE_LAYOUTS = ("slots", "paged")
 ADMISSION_POLICIES = ("priority", "fifo", "sjf")
 SERVE_LOOPS = ("async", "sync")
 
+# host-side profiler spans (``serve.*``): about a microsecond each while no
+# profiler runs; inside a trace they land on the device's clock
+_span = jax.profiler.TraceAnnotation
+
 def _resolve_cache_donation() -> Tuple[str, ...]:
     """Donate the cache operand of every cache-consuming program so the
     pooled KV is rebuilt IN PLACE instead of copied per dispatch — sound
@@ -285,17 +289,18 @@ def _decode_tick(
                 cfg, params, cache, {"tokens": last_token[:, None]}, cur_len,
                 tables, block_size=block_size, attn_impl=attn_impl,
             )
-        # the sampled token lands at position cur_len + 1 -> unique, slot-
-        # and schedule-independent key per token
-        keys = jax.vmap(jax.random.fold_in)(slot_keys, cur_len + 1)
-        toks = jax.vmap(lambda l, k: select_token(l[None], sampling, k)[0])(
-            logits[:, 0, :], keys
-        )
-        if sampling.eos_id >= 0:
-            toks = jnp.where(done, jnp.int32(sampling.eos_id), toks)
-            done = done | (toks == sampling.eos_id)
-        toks = jnp.where(active, toks, 0)
-        last_token = jnp.where(active, toks, last_token)
+        with jax.named_scope("sample"):
+            # the sampled token lands at position cur_len + 1 -> unique,
+            # slot- and schedule-independent key per token
+            keys = jax.vmap(jax.random.fold_in)(slot_keys, cur_len + 1)
+            toks = jax.vmap(
+                lambda l, k: select_token(l[None], sampling, k)[0]
+            )(logits[:, 0, :], keys)
+            if sampling.eos_id >= 0:
+                toks = jnp.where(done, jnp.int32(sampling.eos_id), toks)
+                done = done | (toks == sampling.eos_id)
+            toks = jnp.where(active, toks, 0)
+            last_token = jnp.where(active, toks, last_token)
         return (cache, last_token, cur_len + active, done), toks
 
     carry = (cache, last_token, cur_len, jnp.zeros_like(active))
@@ -2151,10 +2156,9 @@ class ServeSession:
         # the sync loop blocks here until the prefill program completes —
         # time it as host_block_s so overlap_fraction stays comparable with
         # the async loop (whose tok0 fetches are timed in _harvest)
-        tb = time.perf_counter()
-        tok0s = np.asarray(tok0s)
-        req_keys = np.asarray(req_keys, np.uint32)
-        self.stats.host_block_s += time.perf_counter() - tb
+        with self._host_blocked():
+            tok0s = np.asarray(tok0s)
+            req_keys = np.asarray(req_keys, np.uint32)
         eos = self.sampling.eos_id
         for i, req in enumerate(reqs):
             slot, tok0 = row_slot[i], int(tok0s[i])
@@ -2282,9 +2286,19 @@ class ServeSession:
         )
 
     def _drain_finished(self) -> List[CompletedRequest]:
-        done = [self._completed[i] for i in self._just_finished]
-        self._just_finished.clear()
-        return done
+        with _span("serve.accept"):
+            done = [self._completed[i] for i in self._just_finished]
+            self._just_finished.clear()
+            return done
+
+    @contextlib.contextmanager
+    def _host_blocked(self):
+        """Block on device-to-host token transfers inside: timed into
+        ``host_block_s`` and spanned as ``serve.harvest_wait``."""
+        tb = time.perf_counter()
+        with _span("serve.harvest_wait"):
+            yield
+        self.stats.host_block_s += time.perf_counter() - tb
 
     def _prefill_budget(self) -> float:
         """Bucketed prompt tokens this step may admit under the interleaving
@@ -2609,10 +2623,9 @@ class ServeSession:
             return
         if not finals:
             return
-        tb = time.perf_counter()
-        tok0s = np.asarray(tok0s)
-        req_keys = np.asarray(req_keys, np.uint32)
-        self.stats.host_block_s += time.perf_counter() - tb
+        with self._host_blocked():
+            tok0s = np.asarray(tok0s)
+            req_keys = np.asarray(req_keys, np.uint32)
         eos = self.sampling.eos_id
         for i, state in finals:
             slot, tok0 = state.slot, int(tok0s[i])
@@ -2844,7 +2857,7 @@ class ServeSession:
             )
         t0 = time.perf_counter()
         try:
-            with self._mesh_ctx():
+            with _span("serve.step", clock=self.clock), self._mesh_ctx():
                 if self.loop == "async":
                     return self._step_async()
                 return self._step_sync()
@@ -2967,7 +2980,8 @@ class ServeSession:
         async loop is benchmarked against."""
         self._pull_arrivals()
         self._update_shed()
-        self._admit_phase()
+        with _span("serve.admit"):
+            self._admit_phase()
 
         if self.n_active == 0:
             # idle: jump to the next arrival instead of burning empty ticks
@@ -2984,74 +2998,84 @@ class ServeSession:
             self.clock += 1
             return self._drain_finished()
 
-        active, tables, block_size, steps = self._chunk_inputs()
+        with _span("serve.dispatch"):
+            active, tables, block_size, steps = self._chunk_inputs()
+            if self.spec:
+                k = self._draft_k_eff
+                self.cache, toks_f, n_acc_f, _, _ = _spec_tick_jit(
+                    cfg=self.cfg, draft_cfg=self.draft_cfg, params=self.params,
+                    cache=self.cache, last_token=self._last_token,
+                    cur_len=self._cur_len, active=active,
+                    slot_keys=self._slot_keys, tables=tables,
+                    sampling=self.sampling, draft_k=k,
+                    block_size=block_size, attn_impl=self.attn_impl,
+                )
+            elif self.tiers is not None:
+                parts = self._dispatch_tier_chunks(
+                    active, tables, block_size, steps
+                )
+            else:
+                self.cache, toks_f, _ = _decode_tick_jit(
+                    cfg=self.cfg, params=self.params, cache=self.cache,
+                    last_token=self._last_token, cur_len=self._cur_len,
+                    active=active, slot_keys=self._slot_keys, tables=tables,
+                    sampling=self.sampling, steps=steps,
+                    block_size=block_size, attn_impl=self.attn_impl,
+                )
+                parts = [toks_f]
+
         if self.spec:
-            k = self._draft_k_eff
-            self.cache, toks, n_acc, _, _ = _spec_tick_jit(
-                cfg=self.cfg, draft_cfg=self.draft_cfg, params=self.params,
-                cache=self.cache, last_token=self._last_token,
-                cur_len=self._cur_len, active=active,
-                slot_keys=self._slot_keys, tables=tables,
-                sampling=self.sampling, draft_k=k,
-                block_size=block_size, attn_impl=self.attn_impl,
-            )
-            tb = time.perf_counter()
-            toks = np.asarray(toks)              # (draft_k + 1, N)
-            n_acc = np.asarray(n_acc)
-            self.stats.host_block_s += time.perf_counter() - tb
-            # one spec tick on the scheduler clock; the device ran
-            # draft_k + 1 token-steps' worth of work
-            self.clock += 1
-            self.stats.ticks += 1
-            self.stats.work_ticks += k + 1
+            with self._host_blocked():
+                toks = np.asarray(toks_f)        # (draft_k + 1, N)
+                n_acc = np.asarray(n_acc_f)
+            with _span("serve.accept"):
+                # one spec tick on the scheduler clock; the device ran
+                # draft_k + 1 token-steps' worth of work
+                self.clock += 1
+                self.stats.ticks += 1
+                self.stats.work_ticks += k + 1
+
+                states = self._decode_states()
+                self._accept_spec_chunk(
+                    states, toks, n_acc, self.stats.work_ticks, k
+                )
+                for slot, state in enumerate(states):
+                    if state is None:
+                        continue
+                    # per-row uneven advance: mirror the device carry
+                    # exactly (continuing rows accepted all n_acc tokens;
+                    # finished rows' values are reset at the slot's next
+                    # admission)
+                    na = int(n_acc[slot])
+                    self._cur_len[slot] = min(
+                        self._cur_len[slot] + na, self.max_len - 1
+                    )
+                    if na:
+                        self._last_token[slot] = int(toks[na - 1, slot])
+            return self._drain_finished()
+
+        with self._host_blocked():
+            toks = np.asarray(parts[0])          # (steps, N)
+            for p in parts[1:]:
+                # per-rung chunks carry disjoint row masks (zeros
+                # elsewhere), so the merged chunk is the elementwise sum
+                toks = toks + np.asarray(p)
+        with _span("serve.accept"):
+            self.clock += steps
+            self.stats.ticks += steps
+            self.stats.work_ticks += steps
 
             states = self._decode_states()
-            self._accept_spec_chunk(states, toks, n_acc, self.stats.work_ticks, k)
+            self._accept_chunk(states, toks, steps, self.stats.work_ticks)
             for slot, state in enumerate(states):
                 if state is None:
                     continue
-                # per-row uneven advance: mirror the device carry exactly
-                # (continuing rows accepted all n_acc tokens; finished rows'
-                # values are reset at the slot's next admission)
-                na = int(n_acc[slot])
+                # device advanced this row all `steps` steps whether or not
+                # it finished mid-chunk; keep the host view in lockstep
                 self._cur_len[slot] = min(
-                    self._cur_len[slot] + na, self.max_len - 1
+                    self._cur_len[slot] + steps, self.max_len - 1
                 )
-                if na:
-                    self._last_token[slot] = int(toks[na - 1, slot])
-            return self._drain_finished()
-
-        if self.tiers is not None:
-            parts = self._dispatch_tier_chunks(active, tables, block_size, steps)
-        else:
-            self.cache, toks_f, _ = _decode_tick_jit(
-                cfg=self.cfg, params=self.params, cache=self.cache,
-                last_token=self._last_token, cur_len=self._cur_len,
-                active=active, slot_keys=self._slot_keys, tables=tables,
-                sampling=self.sampling, steps=steps, block_size=block_size,
-                attn_impl=self.attn_impl,
-            )
-            parts = [toks_f]
-        tb = time.perf_counter()
-        toks = np.asarray(parts[0])              # (steps, N)
-        for p in parts[1:]:
-            # per-rung chunks carry disjoint row masks (zeros elsewhere),
-            # so the merged chunk is the elementwise sum
-            toks = toks + np.asarray(p)
-        self.stats.host_block_s += time.perf_counter() - tb
-        self.clock += steps
-        self.stats.ticks += steps
-        self.stats.work_ticks += steps
-
-        states = self._decode_states()
-        self._accept_chunk(states, toks, steps, self.stats.work_ticks)
-        for slot, state in enumerate(states):
-            if state is None:
-                continue
-            # device advanced this row all `steps` steps whether or not it
-            # finished mid-chunk; keep the host view in lockstep
-            self._cur_len[slot] = min(self._cur_len[slot] + steps, self.max_len - 1)
-            self._last_token[slot] = int(toks[steps - 1, slot])
+                self._last_token[slot] = int(toks[steps - 1, slot])
         return self._drain_finished()
 
     def _release_predicted_done(self) -> None:
@@ -3088,9 +3112,21 @@ class ServeSession:
         self._release_predicted_done()
         self._pull_arrivals()
         self._update_shed()
-        self._admit_phase()
+        with _span("serve.admit"):
+            self._admit_phase()
 
-        prev, new = self._inflight, None
+        prev = self._inflight
+        with _span("serve.dispatch"):
+            self._inflight = self._dispatch_async(prev)
+        if prev is not None:
+            self._harvest(prev)
+        return self._drain_finished()
+
+    def _dispatch_async(self, prev: Optional[_Inflight]) -> Optional[_Inflight]:
+        """Dispatch the next decode chunk without waiting for the one in
+        flight, and return it; with no row decoding, advance the clock and
+        return ``None``."""
+        new = None
         if self.n_decoding:
             active, tables, block_size, steps = self._chunk_inputs()
             if self.spec:
@@ -3160,32 +3196,35 @@ class ServeSession:
                 self.clock = max(self.clock + 1, self._pending[0][0])
             else:
                 self.clock += 1
-        self._inflight = new
-        if prev is not None:
-            self._harvest(prev)
-        return self._drain_finished()
+        return new
 
     def _harvest(self, fl: _Inflight) -> None:
         """Block on an in-flight chunk's token transfer (the device is
         already executing the next chunk) and run the deferred bookkeeping:
         admit-time first tokens queued since the previous harvest, then the
         chunk's tokens for the rows that were live at its dispatch."""
-        tb = time.perf_counter()
-        if isinstance(fl.toks, tuple):
-            # quality tiers: per-rung chunk parts with disjoint row masks
-            # (zeros elsewhere) — the merged chunk is the elementwise sum
-            toks = np.asarray(fl.toks[0])
-            for p in fl.toks[1:]:
-                toks = toks + np.asarray(p)
-        else:
-            toks = np.asarray(fl.toks)           # (steps, N)
-        n_acc = np.asarray(fl.n_acc) if fl.n_acc is not None else None
-        pend, self._pending_tok0 = self._pending_tok0, []
-        drained = [
-            (states, np.asarray(t0s), idxs) for states, t0s, idxs in pend
-        ]
-        self.stats.host_block_s += time.perf_counter() - tb
+        with self._host_blocked():
+            if isinstance(fl.toks, tuple):
+                # quality tiers: per-rung chunk parts with disjoint row
+                # masks (zeros elsewhere) — the merged chunk is the sum
+                toks = np.asarray(fl.toks[0])
+                for p in fl.toks[1:]:
+                    toks = toks + np.asarray(p)
+            else:
+                toks = np.asarray(fl.toks)       # (steps, N)
+            n_acc = np.asarray(fl.n_acc) if fl.n_acc is not None else None
+            pend, self._pending_tok0 = self._pending_tok0, []
+            drained = [
+                (states, np.asarray(t0s), idxs) for states, t0s, idxs in pend
+            ]
+        with _span("serve.accept"):
+            self._accept_harvest(fl, toks, n_acc, drained)
 
+    def _accept_harvest(self, fl: _Inflight, toks: np.ndarray,
+                        n_acc: Optional[np.ndarray], drained) -> None:
+        """The harvest's bookkeeping on the fetched tokens: first tokens of
+        admits, then the chunk's tokens (reconciling a speculative chunk's
+        lengths first)."""
         eos = self.sampling.eos_id
         for states, tok0s, idxs in drained:
             for state, i in zip(states, idxs):

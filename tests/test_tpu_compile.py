@@ -11,6 +11,7 @@ compilation cache switched off: a program compiled for a described chip
 cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,3 +80,45 @@ def test_paged_attention_compiles_for_v5e(one_chip, no_compile_cache, dtype):
         _spec((B,), jnp.int32, one_chip),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _custom_calls(compiled_text):
+    """(instruction name, op_name) of every TPU custom call."""
+    return [(m.group(1), m.group(2)) for m in re.finditer(
+        r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', compiled_text)]
+
+
+def test_kernel_names_survive_the_callers_scopes(one_chip, no_compile_cache):
+    """Profiles name each kernel's operation after its launcher, inside a
+    model scope and under another jitted caller alike: the instruction is
+    ``%approx_matmul_kernel_call.N`` or ``%paged_attention_kernel_call.N``,
+    and its ``op_name`` carries the caller's scope."""
+    B, H, n_kv, hd, bs, num_blocks, W = 8, 32, 8, 64, 16, 64, 4
+
+    def caller(a, b, q, kn, vn, kp, vp, tbl, cl):
+        with jax.named_scope("dense"):
+            y = approx_matmul_pallas(a, b, multiplier="mul8x8_2",
+                                     interpret=False)
+        with jax.named_scope("attention"):
+            o = paged_attention_kernel_call(q, kn, vn, kp, vp, tbl, cl,
+                                            block_size=bs, interpret=False)
+        return y, o
+
+    text = jax.jit(caller).lower(
+        _spec((8, 2048), jnp.uint8, one_chip),
+        _spec((2048, 2048), jnp.uint8, one_chip),
+        _spec((B, H, hd), jnp.bfloat16, one_chip),
+        _spec((B, n_kv, hd), jnp.bfloat16, one_chip),
+        _spec((B, n_kv, hd), jnp.bfloat16, one_chip),
+        _spec((num_blocks, bs, n_kv, hd), jnp.bfloat16, one_chip),
+        _spec((num_blocks, bs, n_kv, hd), jnp.bfloat16, one_chip),
+        _spec((B, W), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip),
+    ).compile().as_text()
+    calls = _custom_calls(text)
+    names = sorted(n.rsplit(".", 1)[0] for n, _ in calls)
+    assert names == ["approx_matmul_kernel_call", "paged_attention_kernel_call"]
+    for name, op_name in calls:
+        scope = "dense" if name.startswith("approx") else "attention"
+        assert f"/{scope}/" in op_name, (name, op_name)
