@@ -127,8 +127,9 @@ def micro_rows(seed: int = 0):
         q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
         kn = jnp.asarray(rng.normal(size=(B, n_kv, hd)), jnp.float32)
         vn = jnp.asarray(rng.normal(size=(B, n_kv, hd)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(num_blocks, bs, n_kv, hd)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(num_blocks, bs, n_kv, hd)), jnp.float32)
+        # a one-layer pool, (L, blocks, block_size, Hkv * hd)
+        kp = jnp.asarray(rng.normal(size=(1, num_blocks, bs, n_kv * hd)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(1, num_blocks, bs, n_kv * hd)), jnp.float32)
         cur = rng.integers(context // 2, context, (B,)).astype(np.int32)
         tbl = np.full((B, W), num_blocks, np.int32)
         free = list(range(num_blocks))
@@ -140,7 +141,7 @@ def micro_rows(seed: int = 0):
 
         ref_fn = jax.jit(functools.partial(paged_attention_ref, block_size=bs))
         pal_fn = functools.partial(paged_attention_pallas, block_size=bs)
-        args = (q, kn, vn, kp, vp, tbl, curj)
+        args = (q, kn, vn, kp, vp, tbl, curj, jnp.int32(0))
         np.testing.assert_allclose(
             np.asarray(pal_fn(*args)), np.asarray(ref_fn(*args)),
             rtol=2e-5, atol=2e-5,

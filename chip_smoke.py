@@ -159,8 +159,9 @@ def check_paged_attention(key, cfg) -> None:
     q = jax.random.normal(ks[0], (B, H, hd), dt)
     k_new = jax.random.normal(ks[1], (B, n_kv, hd), dt)
     v_new = jax.random.normal(ks[2], (B, n_kv, hd), dt)
-    k_pool = jax.random.normal(ks[3], (num_blocks, BLOCK_SIZE, n_kv, hd), dt)
-    v_pool = jax.random.normal(ks[4], (num_blocks, BLOCK_SIZE, n_kv, hd), dt)
+    # a two-layer pool, (L, blocks, block_size, Hkv * hd); layer 1 attended
+    k_pool = jax.random.normal(ks[3], (2, num_blocks, BLOCK_SIZE, n_kv * hd), dt)
+    v_pool = jax.random.normal(ks[4], (2, num_blocks, BLOCK_SIZE, n_kv * hd), dt)
     cur_len = jax.random.randint(ks[5], (B,), 0, MAX_LEN, jnp.int32)
     # each row holds its blocks up to cur_len in a shuffled pool; later
     # entries are sentinels, and the last row holds none (an idle slot)
@@ -168,7 +169,7 @@ def check_paged_attention(key, cfg) -> None:
     w = jnp.arange(W, dtype=jnp.int32)[None, :]
     table = jnp.where(w * BLOCK_SIZE <= cur_len[:, None], table, num_blocks)
     table = table.at[B - 1].set(num_blocks)
-    args = (q, k_new, v_new, k_pool, v_pool, table, cur_len)
+    args = (q, k_new, v_new, k_pool, v_pool, table, cur_len, jnp.int32(1))
     kernel = compile_on_chip(functools.partial(
         paged_attention_pallas, block_size=BLOCK_SIZE, interpret=False), *args)
     got = np.asarray(kernel(*args).astype(jnp.float32))

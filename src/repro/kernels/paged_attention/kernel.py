@@ -15,8 +15,8 @@ table and streams K/V blocks from the pool straight into VMEM tiles:
 * the new token's K/V (``k_new``/``v_new``, already rotary-embedded at
   ``cur_len``) is fused into the current block's VMEM tile at offset
   ``cur_len % block_size`` before the QK^T — attention never waits on the
-  pool scatter, which the caller runs in parallel to persist the token for
-  the NEXT step;
+  pool scatter, which the caller orders after this kernel to persist the
+  token for the NEXT step;
 * per-block scores feed a running online softmax (``m``/``l``/``acc``
   scratch carried across the ``w`` walk, flushed at ``w == W - 1``);
 * sentinel table entries (``id >= num_blocks``: unallocated / padding
@@ -36,13 +36,21 @@ bitwise.  Greedy ARGMAX outputs stay bit-identical across serve traces
 (asserted in tests/test_paged.py); ``ref.py`` is the exact-math oracle the
 property tests difference against.
 
+Pool layout: ``(L, num_blocks, block_size, Hkv * hd)``, head-major in the
+last dimension, passed WHOLE with the layer to attend as one more
+scalar-prefetch operand; the index maps read ``(layer, fetch[b, w], 0, 0)``.
+Each DMA'd tile is a lane-dense ``(block_size, Hkv * hd)`` slab (granite:
+``(16, 512)`` bf16, whole ``(16, 128)`` tiles), and the body takes each KV
+head's ``(block_size, hd)`` keys and values by a static lane slice.  Taking
+the whole pool is what lets the caller's layer loop carry the pool and
+update it in place: no per-layer slice of the pool ever exists.
+
 TPU lowering note: Mosaic index maps may only read scalars from SMEM, so
 the "last valid block at or before ``w``" search runs in the jitted
 wrapper (a cumulative max over the table) and the index map reads one
-entry of its result.  Tiles are ``(block_size, Hkv, hd)``; granite's
-``hd`` 64 / ``block_size`` 16 compiles for v5e in bf16 and f32
-(``tests/test_tpu_compile.py``).  Interpret mode (CPU CI,
-``REPRO_FORCE_INTERPRET=1``) runs this exact kernel body.
+entry of its result.  Granite's ``hd`` 64 / ``block_size`` 16 compiles for
+v5e in bf16 and f32 (``tests/test_tpu_compile.py``).  Interpret mode (CPU
+CI, ``REPRO_FORCE_INTERPRET=1``) runs this exact kernel body.
 """
 from __future__ import annotations
 
@@ -62,10 +70,11 @@ def _kernel(
     tbl_ref,      # (B, W) int32 scalar-prefetch: physical block ids
     len_ref,      # (B,)  int32 scalar-prefetch: new-token positions
     fetch_ref,    # (B, W) int32 scalar-prefetch: block DMA'd at (b, w)
+    layer_ref,    # (1,)  int32 scalar-prefetch: the pool layer attended
     q_ref,        # (1, H, hd) this row's query
-    kn_ref,       # (1, Hkv, hd) new token K (post-rope)
-    vn_ref,       # (1, Hkv, hd) new token V
-    k_ref,        # (1, block_size, Hkv, hd) pool block block_table[b, w]
+    kn_ref,       # (1, 1, Hkv*hd) new token K (post-rope), head-major
+    vn_ref,       # (1, 1, Hkv*hd) new token V
+    k_ref,        # (1, 1, block_size, Hkv*hd) pool[layer, block_table[b, w]]
     v_ref,
     out_ref,      # (1, H, hd)
     m_ref,        # (H, 1) f32 scratch: running max
@@ -100,22 +109,26 @@ def _kernel(
         H, hd = q_ref.shape[1], q_ref.shape[2]
         g = H // n_kv
         q = q_ref[0].astype(jnp.float32)                 # (H, hd)
-        k = k_ref[0].astype(jnp.float32)                 # (bs, Hkv, hd)
-        v = v_ref[0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)              # (bs, Hkv*hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         # fused token append: overwrite row `off` of the CURRENT block's
         # VMEM tile with the new K/V — the HBM pool still holds last step's
         # contents, and never needs to be read-after-written within a step
         off = cur % block_size
-        row = jax.lax.broadcasted_iota(jnp.int32, (block_size, 1, 1), 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (block_size, 1), 0)
         sel = (row == off) & (w == cur // block_size)
-        k = jnp.where(sel, kn_ref[0].astype(jnp.float32)[None], k)
-        v = jnp.where(sel, vn_ref[0].astype(jnp.float32)[None], v)
+        k = jnp.where(sel, kn_ref[0].astype(jnp.float32), k)
+        v = jnp.where(sel, vn_ref[0].astype(jnp.float32), v)
 
         scale = 1.0 / jnp.sqrt(jnp.float32(hd))
         qg = (q * scale).reshape(n_kv, g, hd)
-        s = jnp.einsum(
-            "hgd,thd->hgt", qg, k, preferred_element_type=jnp.float32
-        )
+        # KV head h is lanes [h*hd, (h+1)*hd) of the tile: static slices
+        heads = [slice(h * hd, (h + 1) * hd) for h in range(n_kv)]
+        s = jnp.stack([
+            jnp.einsum("gd,td->gt", qg[h], k[:, hs],
+                       preferred_element_type=jnp.float32)
+            for h, hs in enumerate(heads)
+        ])                                               # (Hkv, g, bs)
         pos = w * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, block_size), 2
         )
@@ -128,10 +141,11 @@ def _kernel(
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)                           # (H, bs)
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jnp.einsum(
-            "hgt,thd->hgd", p.reshape(n_kv, g, block_size), v,
-            preferred_element_type=jnp.float32,
-        ).reshape(H, hd)
+        pg = p.reshape(n_kv, g, block_size)
+        pv = jnp.stack([
+            jnp.dot(pg[h], v[:, hs], preferred_element_type=jnp.float32)
+            for h, hs in enumerate(heads)
+        ]).reshape(H, hd)
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
@@ -148,26 +162,29 @@ def paged_attention_kernel_call(
     q: jax.Array,            # (B, H, hd)
     k_new: jax.Array,        # (B, Hkv, hd)
     v_new: jax.Array,        # (B, Hkv, hd)
-    k_pool: jax.Array,       # (num_blocks, block_size, Hkv, hd)
+    k_pool: jax.Array,       # (L, num_blocks, block_size, Hkv * hd)
     v_pool: jax.Array,
     block_table: jax.Array,  # (B, W) int32, sentinel == num_blocks
     cur_len: jax.Array,      # (B,) int32
+    layer: jax.Array,        # () int32: the pool layer to attend
     *,
     block_size: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """One decode step of paged GQA: (B, H, hd) f32 attention outputs.
+    """One decode step of paged GQA over pool layer ``layer``: (B, H, hd)
+    f32 attention outputs.
 
-    Table/length *contents* are traced data (scalar-prefetch operands), so
-    one compiled program serves every context layout — same discipline as
-    the gather path.  The pool operands are read-only: persisting the new
-    token is the caller's (cheap, O(B*Hkv*hd)) scatter, free to run in
-    parallel with this kernel.
+    Table/length/layer *contents* are traced data (scalar-prefetch
+    operands), so one compiled program serves every context layout and
+    every layer — same discipline as the gather path.  The pool operands
+    are read-only: persisting the new token is the caller's (cheap,
+    O(B*Hkv*hd)) scatter, ordered after this call.
     """
     B, H, hd = q.shape
-    num_blocks, bs, n_kv, hd_k = k_pool.shape
+    n_kv = k_new.shape[1]
+    _, num_blocks, bs, lanes = k_pool.shape
     assert bs == block_size, (bs, block_size)
-    assert hd == hd_k and H % n_kv == 0, (q.shape, k_pool.shape)
+    assert lanes == n_kv * hd and H % n_kv == 0, (q.shape, k_new.shape, k_pool.shape)
     W = block_table.shape[1]
 
     # The paged indirection.  A BlockSpec index map always implies a fetch,
@@ -186,21 +203,21 @@ def paged_attention_kernel_call(
         0,
     )
 
-    def pool_index(b, w, tbl, lens, fetch):
-        return (fetch[b, w], 0, 0, 0)
+    def pool_index(b, w, tbl, lens, fetch, layer):
+        return (layer[0], fetch[b, w], 0, 0)
 
-    def row_index(b, w, tbl, lens, fetch):
+    def row_index(b, w, tbl, lens, fetch, layer):
         return (b, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, W),
         in_specs=[
             pl.BlockSpec((1, H, hd), row_index),
-            pl.BlockSpec((1, n_kv, hd), row_index),
-            pl.BlockSpec((1, n_kv, hd), row_index),
-            pl.BlockSpec((1, block_size, n_kv, hd), pool_index),
-            pl.BlockSpec((1, block_size, n_kv, hd), pool_index),
+            pl.BlockSpec((1, 1, lanes), row_index),
+            pl.BlockSpec((1, 1, lanes), row_index),
+            pl.BlockSpec((1, 1, block_size, lanes), pool_index),
+            pl.BlockSpec((1, 1, block_size, lanes), pool_index),
         ],
         out_specs=pl.BlockSpec((1, H, hd), row_index),
         scratch_shapes=[
@@ -226,4 +243,8 @@ def paged_attention_kernel_call(
         out_shape=jax.ShapeDtypeStruct((B, H, hd), jnp.float32),
         interpret=interpret,
         **kwargs,
-    )(block_table, cur_len, fetch, q, k_new, v_new, k_pool, v_pool)
+    )(
+        block_table, cur_len, fetch, jnp.reshape(layer, (1,)).astype(jnp.int32),
+        q, k_new.reshape(B, 1, lanes), v_new.reshape(B, 1, lanes),
+        k_pool, v_pool,
+    )

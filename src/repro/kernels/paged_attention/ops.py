@@ -9,7 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.interpret import default_interpret as _default_interpret
+# the policy is looked up per call, so a test can patch it after import
+from repro.kernels import interpret as _interpret
 from repro.kernels.paged_attention.kernel import paged_attention_kernel_call
 
 __all__ = ["paged_attention_pallas", "validate_tp_heads"]
@@ -42,10 +43,11 @@ def paged_attention_pallas(
     q: jax.Array,            # (B, H, hd) post-rope queries, one decode step
     k_new: jax.Array,        # (B, Hkv, hd) new token K (post-rope)
     v_new: jax.Array,        # (B, Hkv, hd) new token V
-    k_pool: jax.Array,       # (num_blocks, block_size, Hkv, hd) one layer
+    k_pool: jax.Array,       # (L, num_blocks, block_size, Hkv * hd) whole pool
     v_pool: jax.Array,
     block_table: jax.Array,  # (B, W) physical block ids, sentinel == num_blocks
     cur_len: jax.Array,      # (B,) new-token positions
+    layer: jax.Array | int,  # the pool layer attended
     *,
     block_size: int,
     interpret: bool | None = None,
@@ -58,16 +60,22 @@ def paged_attention_pallas(
     ``models.attention.paged_decode_attention``).
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = _interpret.default_interpret()
     B, H, hd = q.shape
-    num_blocks, bs, n_kv, hd_k = k_pool.shape
+    n_kv = k_new.shape[1]
+    if k_pool.ndim != 4:
+        raise ValueError(
+            f"pool must be (L, num_blocks, block_size, Hkv*hd), got {k_pool.shape}"
+        )
+    _, num_blocks, bs, lanes = k_pool.shape
     if bs != block_size:
         raise ValueError(f"pool block_size {bs} != block_size arg {block_size}")
     if v_pool.shape != k_pool.shape:
         raise ValueError(f"k/v pool shapes differ: {k_pool.shape} vs {v_pool.shape}")
-    if hd != hd_k or H % n_kv:
+    if lanes != n_kv * hd or H % n_kv:
         raise ValueError(
-            f"q heads/dim {(H, hd)} incompatible with pool {(n_kv, hd_k)}"
+            f"q heads/dim {(H, hd)} incompatible with new-token K "
+            f"{k_new.shape} and pool rows of {lanes}"
         )
     if k_new.shape != (B, n_kv, hd) or v_new.shape != (B, n_kv, hd):
         raise ValueError(
@@ -87,6 +95,7 @@ def paged_attention_pallas(
         v_pool,
         block_table.astype(jnp.int32),
         cur_len.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32),
         block_size=block_size,
         interpret=interpret,
     )

@@ -26,25 +26,27 @@ def paged_attention_ref(
     q: jax.Array,            # (B, H, hd)
     k_new: jax.Array,        # (B, Hkv, hd)
     v_new: jax.Array,        # (B, Hkv, hd)
-    k_pool: jax.Array,       # (num_blocks, block_size, Hkv, hd)
+    k_pool: jax.Array,       # (L, num_blocks, block_size, Hkv * hd)
     v_pool: jax.Array,
     block_table: jax.Array,  # (B, W) int32, sentinel == num_blocks
     cur_len: jax.Array,      # (B,) int32
+    layer: jax.Array | int,  # the pool layer attended
     *,
     block_size: int,
 ) -> jax.Array:
-    """Exact-softmax paged GQA; (B, H, hd) f32.  Rows with no valid
-    position (every block sentinel) return zeros, matching the kernel's
-    empty-row flush."""
+    """Exact-softmax paged GQA over pool layer ``layer``; (B, H, hd) f32.
+    Rows with no valid position (every block sentinel) return zeros,
+    matching the kernel's empty-row flush."""
     B, H, hd = q.shape
-    num_blocks, bs, n_kv, _ = k_pool.shape
+    n_kv = k_new.shape[1]
+    num_blocks = k_pool.shape[1]
     W = block_table.shape[1]
     g = H // n_kv
     S = W * block_size
 
     clamped = jnp.minimum(block_table, num_blocks - 1)
-    kg = k_pool[clamped].reshape(B, S, n_kv, hd).astype(jnp.float32)
-    vg = v_pool[clamped].reshape(B, S, n_kv, hd).astype(jnp.float32)
+    kg = k_pool[layer, clamped].reshape(B, S, n_kv, hd).astype(jnp.float32)
+    vg = v_pool[layer, clamped].reshape(B, S, n_kv, hd).astype(jnp.float32)
 
     pos = jnp.arange(S, dtype=jnp.int32)
     at_cur = pos[None, :] == cur_len[:, None]                    # (B, S)

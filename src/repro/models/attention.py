@@ -298,12 +298,53 @@ def decode_attention(
     return out, (k_cache, v_cache)
 
 
+@jax.named_scope("kv_write")
+def _pool_write(
+    k_pool: jax.Array,            # (L, num_blocks, block_size, Hkv*hd)
+    v_pool: jax.Array,
+    layer: jax.Array,             # () pool layer written
+    block_table: jax.Array,       # (B, W) int32 physical block ids
+    pos: jax.Array,               # (B, S) positions written
+    k: jax.Array,                 # (B, S, Hkv, hd)
+    v: jax.Array,
+    block_size: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """Persist K/V rows at positions ``pos`` of pool layer ``layer``: one
+    scatter per pool at ``[layer, phys, off]``, the whole pool updated in
+    place.  Targets through a sentinel entry or past the table go to block
+    ``num_blocks``, out of bounds, and the scatter DROPS them under jit
+    (``dynamic_update_slice`` would CLAMP; do not swap the write path), so
+    overshoot and inactive rows write nothing."""
+    num_blocks = k_pool.shape[1]
+    W = block_table.shape[1]
+    B, S = pos.shape
+    blk = pos // block_size
+    off = pos % block_size
+    phys = jnp.take_along_axis(block_table, jnp.minimum(blk, W - 1), axis=1)
+    phys = jnp.where(blk < W, phys, num_blocks)  # past-table -> dropped
+    return (
+        k_pool.at[layer, phys, off].set(k.reshape(B, S, -1).astype(k_pool.dtype)),
+        v_pool.at[layer, phys, off].set(v.reshape(B, S, -1).astype(v_pool.dtype)),
+    )
+
+
+def _pool_gather(pool: jax.Array, layer: jax.Array, block_table: jax.Array, n_kv: int):
+    """(B, W*block_size, Hkv, hd) view of each row's blocks of one pool
+    layer.  Sentinel entries clamp to the last real block: bounded garbage
+    the caller's ``kv_len`` mask zeroes exactly.  Only the small gathered
+    view is reshaped, never the pool."""
+    B, W = block_table.shape
+    g = pool[layer, block_table]                 # (B, W, block_size, Hkv*hd)
+    return g.reshape(B, W * pool.shape[2], n_kv, -1)
+
+
 @jax.named_scope("attention")
 def paged_decode_attention(
     x: jax.Array,                 # (B, 1, d)
     p: AttnParams,
-    k_blocks: jax.Array,          # (num_blocks, block_size, Hkv, hd) one layer
-    v_blocks: jax.Array,
+    k_pool: jax.Array,            # (L, num_blocks, block_size, Hkv*hd) whole pool
+    v_pool: jax.Array,
+    layer: jax.Array,             # () this layer's index into the pool
     block_table: jax.Array,       # (B, W) int32 physical block ids
     cur_len: jax.Array,           # (B,) current lengths (new token index)
     *,
@@ -317,11 +358,13 @@ def paged_decode_attention(
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """``decode_attention`` against a paged KV cache: append K/V into the
     request's current block, attend over its blocks via the block table.
+    Returns ``(out, (k_pool, v_pool))`` with the whole pool updated.
 
     Row ``b``'s logical position ``pos`` lives at offset ``pos % block_size``
-    of physical block ``block_table[b, pos // block_size]``.  The table is
-    fixed-width (``W = max_len // block_size``) with unallocated entries set
-    to the sentinel ``num_blocks``, so ONE compiled program serves any
+    of physical block ``block_table[b, pos // block_size]`` of pool layer
+    ``layer``, in lanes ``[h*hd, (h+1)*hd)`` for KV head ``h``.  The table
+    is fixed-width (``W = max_len // block_size``) with unallocated entries
+    set to the sentinel ``num_blocks``, so ONE compiled program serves any
     context layout; table *contents* are traced data.  That content-
     agnosticism is what makes the scheduler's copy-on-write prefix sharing
     free at this layer: several rows' tables may point at the SAME physical
@@ -331,12 +374,13 @@ def paged_decode_attention(
     read-path change is needed (pinned by tests/test_prefix_sharing.py
     under both impls).
 
-    * the append scatter targets the sentinel for rows past their allocated
-      blocks (or past the table) — out-of-bounds scatter updates are DROPPED
-      under jit (dynamic_update_slice would CLAMP; do not swap the write
-      path), so overshoot and inactive rows write nothing;
-    * ``attn_impl="gather"`` (the parity oracle): ``k_blocks[block_table]``
-      materializes a transient (B, W*block_size, Hkv, hd) view — sentinel
+    The pool is taken and returned WHOLE, so a layer loop that carries it
+    updates it in place (``transformer._paged_layers``):
+
+    * the append (``_pool_write``) targets the sentinel for rows past their
+      allocated blocks (or past the table), and those writes are dropped;
+    * ``attn_impl="gather"`` (the parity oracle) writes first, then
+      gathers a transient (B, W*block_size, Hkv, hd) view — sentinel
       entries clamp to the last real block, bounded garbage the ``kv_len``
       mask zeroes *exactly* (scores at ~-1e30, softmax probability 0.0, AV
       bit-identical to the slot layout's in-place cache);
@@ -344,14 +388,15 @@ def paged_decode_attention(
       VMEM tiles (``kernels.paged_attention``): the transient never exists
       in HBM, sentinel blocks are skipped by predicate, and the new token
       is fused into the current block's tile — the kernel reads the
-      *pre-scatter* pool, so attention and the persistence scatter run in
-      parallel.  Attention floats agree with the gather path to f32
-      roundoff (online vs fused softmax reduction order); greedy tokens are
-      bit-identical across serve traces (tests/test_paged.py).  That token
-      contract assumes an f32 pool: under reduced cache dtypes the gather
-      path additionally rounds its softmax *probs* to the cache dtype
-      (``attention_core``) while the kernel keeps them f32, so bf16-cache
-      parity is statistical — same discipline as the quantized modes.
+      *pre-write* pool, and the write is ordered after the kernel by data
+      flow, so XLA keeps no copy of the old pool.  Attention floats agree
+      with the gather path to f32 roundoff (online vs fused softmax
+      reduction order); greedy tokens are bit-identical across serve traces
+      (tests/test_paged.py).  That token contract assumes an f32 pool:
+      under reduced cache dtypes the gather path additionally rounds its
+      softmax *probs* to the cache dtype (``attention_core``) while the
+      kernel keeps them f32, so bf16-cache parity is statistical — same
+      discipline as the quantized modes.
 
     Projections route through ``layers.dense`` exactly as in
     ``decode_attention`` — every execution mode (incl. the Pallas
@@ -364,17 +409,11 @@ def paged_decode_attention(
         rope_theta=rope_theta, use_rope=use_rope,
     )
     hd = q.shape[3]
-    num_blocks = k_blocks.shape[0]
-    W = block_table.shape[1]
-    with jax.named_scope("kv_write"):
-        blk = cur_len // block_size
-        off = cur_len % block_size
-        phys = jnp.take_along_axis(
-            block_table, jnp.minimum(blk, W - 1)[:, None], axis=1
-        )[:, 0]
-        phys = jnp.where(blk < W, phys, num_blocks)  # past-table -> dropped
-        new_k = k_blocks.at[phys, off].set(k[:, 0].astype(k_blocks.dtype))
-        new_v = v_blocks.at[phys, off].set(v[:, 0].astype(v_blocks.dtype))
+    # the fused token is cast to the POOL dtype first — the kernel must
+    # attend the same rounded value every later step will read back
+    k = k.astype(k_pool.dtype)
+    v = v.astype(v_pool.dtype)
+    pos = cur_len[:, None]
     if attn_impl == "pallas":
         from repro.kernels.paged_attention import (
             paged_attention_pallas,
@@ -382,56 +421,61 @@ def paged_decode_attention(
         )
         from repro.parallel.sharding import mesh_axis_size
 
-        # pre-scatter pool operands on purpose: the kernel fuses the new
-        # token in VMEM, so the scatter above only persists it for the
-        # NEXT step and never serializes with this step's attention.  The
-        # fused token is cast to the POOL dtype first — the kernel must
-        # attend the same rounded value every later step will read back
-        def call(qh, kh, vh, kp, vp, bt, cl):
+        def call(qh, kh, vh, kp, vp, bt, cl, lyr):
             return paged_attention_pallas(
-                qh, kh, vh, kp, vp, bt, cl, block_size=block_size
+                qh, kh, vh, kp, vp, bt, cl, lyr, block_size=block_size
             )
 
         tp = mesh_axis_size("model")
         if tp > 1:
             # pallas_call is not partitioned by GSPMD — map it per shard of
             # the installed mesh.  Each shard runs the unmodified kernel
-            # over its Hkv/tp pool heads and H/tp query heads (group
-            # structure preserved, see validate_tp_heads); the block table
-            # and lengths replicate, so every shard walks the same
+            # over its Hkv/tp pool heads (whole heads: the pool's last dim
+            # is head-major) and H/tp query heads (group structure
+            # preserved, see validate_tp_heads); the block table, lengths
+            # and layer index replicate, so every shard walks the same
             # host-global table.
             from jax.sharding import PartitionSpec as P
 
             validate_tp_heads(n_heads, n_kv, tp)
             hspec = P(None, "model", None)
-            pspec = P(None, None, "model", None)
+            pspec = P(None, None, None, "model")
             call = jax.shard_map(
                 call,
                 in_specs=(hspec, hspec, hspec, pspec, pspec,
-                          P(None, None), P(None)),
+                          P(None, None), P(None), P()),
                 out_specs=hspec,
                 check_vma=False,
             )
         out = call(
-            q[:, 0],
-            k[:, 0].astype(k_blocks.dtype), v[:, 0].astype(v_blocks.dtype),
-            k_blocks, v_blocks,
-            block_table, cur_len,
+            q[:, 0], k[:, 0], v[:, 0], k_pool, v_pool,
+            block_table, cur_len, layer,
         )[:, None]
+        # the write below consumes the pool only after the kernel's output
+        # exists: the kernel reads the old pool, the write then updates it
+        # in place, and no copy of the old pool is needed
+        out, k_pool, v_pool = jax.lax.optimization_barrier((out, k_pool, v_pool))
+        k_pool, v_pool = _pool_write(
+            k_pool, v_pool, layer, block_table, pos, k, v, block_size
+        )
     else:
-        kg = new_k[block_table].reshape(B, W * block_size, n_kv, hd)
-        vg = new_v[block_table].reshape(B, W * block_size, n_kv, hd)
+        k_pool, v_pool = _pool_write(
+            k_pool, v_pool, layer, block_table, pos, k, v, block_size
+        )
+        kg = _pool_gather(k_pool, layer, block_table, n_kv)
+        vg = _pool_gather(v_pool, layer, block_table, n_kv)
         out = attention_core(q, kg, vg, causal=False, kv_len=cur_len + 1, q_chunk=1)
     out = L.dense(out.reshape(B, 1, n_heads * hd), p.wo, cfg)
-    return out, (new_k, new_v)
+    return out, (k_pool, v_pool)
 
 
 @jax.named_scope("attention")
 def paged_verify_attention(
     x: jax.Array,                 # (B, S, d) — S = draft_k + 1 verify positions
     p: AttnParams,
-    k_blocks: jax.Array,          # (num_blocks, block_size, Hkv, hd) one layer
-    v_blocks: jax.Array,
+    k_pool: jax.Array,            # (L, num_blocks, block_size, Hkv*hd) whole pool
+    v_pool: jax.Array,
+    layer: jax.Array,             # () this layer's index into the pool
     block_table: jax.Array,       # (B, W) int32 physical block ids
     cur_len: jax.Array,           # (B,) position of the FIRST verify token
     *,
@@ -474,17 +518,11 @@ def paged_verify_attention(
     pos = cur_len[:, None] + jnp.arange(S, dtype=cur_len.dtype)[None, :]
     if use_rope:
         q, k = L.apply_rope(q, k, pos, theta=rope_theta)
-    num_blocks = k_blocks.shape[0]
-    W = block_table.shape[1]
-    with jax.named_scope("kv_write"):
-        blk = pos // block_size                  # (B, S)
-        off = pos % block_size
-        phys = jnp.take_along_axis(block_table, jnp.minimum(blk, W - 1), axis=1)
-        phys = jnp.where(blk < W, phys, num_blocks)  # past-table -> dropped
-        new_k = k_blocks.at[phys, off].set(k.astype(k_blocks.dtype))
-        new_v = v_blocks.at[phys, off].set(v.astype(v_blocks.dtype))
-    kg = new_k[block_table].reshape(B, W * block_size, n_kv, hd)
-    vg = new_v[block_table].reshape(B, W * block_size, n_kv, hd)
+    k_pool, v_pool = _pool_write(
+        k_pool, v_pool, layer, block_table, pos, k, v, block_size
+    )
+    kg = _pool_gather(k_pool, layer, block_table, n_kv)
+    vg = _pool_gather(v_pool, layer, block_table, n_kv)
     outs = [
         attention_core(
             q[:, j : j + 1], kg, vg, causal=False,
@@ -494,7 +532,7 @@ def paged_verify_attention(
     ]
     out = jnp.concatenate(outs, axis=1)          # (B, S, H, hd)
     out = L.dense(out.reshape(B, S, n_heads * hd), p.wo, cfg)
-    return out, (new_k, new_v)
+    return out, (k_pool, v_pool)
 
 
 def paged_chunk_prefill_attention(*args, **kwargs):

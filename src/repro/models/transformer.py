@@ -16,7 +16,8 @@ Caches are pytrees stacked the same way; ``decode_step`` scans over
 Name scopes label each layer's operations in the compiled HLO (``op_name``)
 and so in device profiles: ``embed``, ``norm``, ``attention``, ``kv_write``,
 ``dense`` and ``mlp`` (the FFN's gate and activation); an operation belongs to
-the innermost scope on its path, and what XLA adds (the scan's copies) to none.
+the innermost scope on its path, and what XLA adds (the loops' slices of the
+stacked weights) to none.
 """
 from __future__ import annotations
 
@@ -380,6 +381,15 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int, dtype=j
     requests are resident — the block table (see ``serve.scheduler``) maps
     each request's logical positions onto its owned blocks.
 
+    Leaves are ``(L, num_blocks, block_size, Hkv * hd)``, head-major in the
+    last dimension: KV head ``h`` of a row is lanes ``[h*hd, (h+1)*hd)``.
+    That is the layout every reader and writer uses (the Pallas kernel's
+    ``(block_size, Hkv*hd)`` tiles, the row scatters), so XLA stores the
+    pool as it is declared and never relays it out; with ``(Hkv, hd)`` as
+    the minor pair, a bf16 ``(8, 64)`` pair would pad its ``(16, 128)``
+    tile fourfold, so XLA would store the pool in another layout and copy
+    each layer into row-major and back around every read and write.
+
     Attention families only: SSM/hybrid decode state is O(1) per request
     (conv tap + ssm state, no sequence axis), so there is nothing to page —
     those families keep the slot layout."""
@@ -389,7 +399,7 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int, dtype=j
             "sequence axis; the paged layout applies to attention-family "
             "KV caches only"
         )
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -532,25 +542,18 @@ def paged_decode_step(
     # row-parallel wo/w_down psum re-materializes it (no-op off-mesh)
     x = constrain(x, ("batch", None, None))
 
-    a = cfg.approx
-
-    def body(x, scanned):
-        layer, kc, vc = scanned
-        h, (kc, vc) = paged_decode_attention(
-            L.rms_norm(x, layer["ln1"]), layer["attn"], kc, vc,
-            block_tables, cur_len,
+    def attend(xn, attn, kp, vp, l):
+        return paged_decode_attention(
+            xn, attn, kp, vp, l, block_tables, cur_len,
             block_size=block_size,
-            n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads, cfg=a,
+            n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads, cfg=cfg.approx,
             rope_theta=cfg.rope_theta,
             use_rope=cfg.pos_embedding in ("rope", "m_rope"),
             attn_impl=attn_impl,
         )
-        return _decode_mlp(cfg, x + h, layer, a), (kc, vc)
 
-    x, (k_new, v_new) = _scan_decode(
-        body, x, (params["layers"], cache["k"], cache["v"]), cfg.scan_layers
-    )
-    return _head(cfg, params, x), {"k": k_new, "v": v_new}
+    x, cache = _paged_layers(cfg, params, cache, x, attend)
+    return _head(cfg, params, x), cache
 
 
 def paged_verify_step(
@@ -601,26 +604,44 @@ def paged_verify_step(
         ).astype(dtype)
     x = constrain(x, ("batch", None, None))
 
-    a = cfg.approx
-
-    def body(x, scanned):
-        layer, kc, vc = scanned
-        h, (kc, vc) = paged_verify_attention(
-            L.rms_norm(x, layer["ln1"]), layer["attn"], kc, vc,
-            block_tables, cur_len,
+    def attend(xn, attn, kp, vp, l):
+        return paged_verify_attention(
+            xn, attn, kp, vp, l, block_tables, cur_len,
             block_size=block_size,
-            n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads, cfg=a,
+            n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads, cfg=cfg.approx,
             rope_theta=cfg.rope_theta,
             use_rope=cfg.pos_embedding in ("rope", "m_rope"),
         )
-        x = x + h
-        return x + _ffn(L.rms_norm(x, layer["ln2"]), layer["ffn"], a,
-                        cfg.fuse_gate_up), (kc, vc)
 
-    x, (k_new, v_new) = _scan_decode(
-        body, x, (params["layers"], cache["k"], cache["v"]), cfg.scan_layers
-    )
-    return _head(cfg, params, x), {"k": k_new, "v": v_new}
+    x, cache = _paged_layers(cfg, params, cache, x, attend)
+    return _head(cfg, params, x), cache
+
+
+def _paged_layers(cfg: ModelConfig, params, cache, x, attend):
+    """The layer loop of the paged decode and verify passes.  It carries
+    ``(x, k_pool, v_pool)`` and scans only the layer weights, with each
+    layer's index: ``attend(x_normed, attn_params, k_pool, v_pool, l)``
+    writes layer ``l``'s new rows into the WHOLE pool and returns it, so the
+    pool is updated in place across layers — no layer of it is sliced out,
+    relaid or stacked back.  ``cfg.scan_layers=False`` unrolls the same
+    carry."""
+    a = cfg.approx
+
+    def body(carry, scanned):
+        x, kp, vp = carry
+        layer, l = scanned
+        h, (kp, vp) = attend(L.rms_norm(x, layer["ln1"]), layer["attn"], kp, vp, l)
+        return (_decode_mlp(cfg, x + h, layer, a), kp, vp), None
+
+    carry = (x, cache["k"], cache["v"])
+    idx = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    if cfg.scan_layers:
+        carry, _ = jax.lax.scan(body, carry, (params["layers"], idx))
+    else:
+        for i in range(cfg.num_layers):
+            carry, _ = body(carry, (_layer_slice(params["layers"], i), idx[i]))
+    x, k, v = carry
+    return x, dict(cache, k=k, v=v)
 
 
 def paged_chunk_prefill_step(

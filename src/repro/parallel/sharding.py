@@ -190,12 +190,14 @@ def cache_pspecs(cfg: ModelConfig, mesh: Mesh, cache_shape: Any, *, layout: str 
     sequence-parallel (SP) over model.
 
     ``layout="paged"`` (block pool, leaves ``(L, num_blocks, block_size,
-    Hkv, hd)``): block *contents* shard along the KV-head dim over model —
-    each shard holds ``Hkv/tp`` heads of every block, so the host-global
-    block tables index all shards identically. The block dim is never
+    Hkv * hd)``, head-major in the last dim): block *contents* shard the
+    last dim over model when ``Hkv`` divides it — each shard holds
+    ``Hkv/tp`` whole heads of every block, so the host-global block tables
+    index all shards identically. Divisibility is checked on ``Hkv``, not
+    on the merged width: a width that divides while ``Hkv`` does not would
+    split heads, so such a pool simply replicates. The block dim is never
     sharded (tables are host state) and there is no SP fallback: splitting
-    ``block_size`` would partition the softmax *within* single blocks. When
-    ``Hkv`` does not divide the model axis the pool simply replicates.
+    ``block_size`` would partition the softmax *within* single blocks.
     """
     if layout not in ("slots", "paged"):
         raise ValueError(f"cache_pspecs: unknown layout {layout!r}")
@@ -207,7 +209,7 @@ def cache_pspecs(cfg: ModelConfig, mesh: Mesh, cache_shape: Any, *, layout: str 
         ks = jax.tree_util.keystr(path)
         shape = leaf.shape
         spec = [None] * len(shape)
-        if (ks.endswith("['k']") or ks.endswith("['v']")) and tp and shape[3] % tp_size == 0:
+        if (ks.endswith("['k']") or ks.endswith("['v']")) and tp and cfg.num_kv_heads % tp_size == 0:
             spec[3] = tp
         return NamedSharding(mesh, prune_pspec(mesh, P(*spec), shape))
 

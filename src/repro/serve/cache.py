@@ -7,8 +7,9 @@ Two cache layouts share this module:
   request reserves a worst-case ``max_len`` stripe for its whole lifetime;
 * **paged** (PR 3): K/V live in a global pool of fixed-size blocks
   (``transformer.init_paged_cache`` leaves ``(L, num_blocks, block_size,
-  Hkv, hd)``) handed out by ``BlockPool``; each request holds only the
-  blocks its *actual* context occupies, recorded in a fixed-width
+  Hkv * hd)``, head-major in the last dimension) handed out by
+  ``BlockPool``; each request holds only the blocks its *actual* context
+  occupies, recorded in a fixed-width
   per-request block table (``(num_slots, max_len // block_size)`` int32,
   unallocated entries == ``num_blocks``).  Mixed context lengths then share
   HBM instead of each reserving the worst case.
@@ -237,7 +238,7 @@ def scatter_prompt_blocks(
     block_size: int,
 ) -> Any:
     """Write fused-prefill K/V stacks (each (L, A, S_bucket, Hkv, hd)) into
-    the paged cache (leaves (L, num_blocks, block_size, Hkv, hd)).
+    the paged cache (leaves (L, num_blocks, block_size, Hkv * hd)).
 
     ``block_ids`` is (A, nb) int32 with ``nb == ceil(S_bucket / block_size)``:
     row ``i``'s ``j``-th entry is the physical block receiving positions
@@ -258,7 +259,9 @@ def scatter_prompt_blocks(
     ids = block_ids.reshape(-1)
 
     def write(full, part):
-        part = part.reshape(L, A * nb, block_size, *part.shape[3:])
+        # heads merge into the pool's head-major last dim: a reshape of the
+        # prompt's K/V, never of the pool
+        part = part.reshape(L, A * nb, block_size, full.shape[3])
         return full.at[:, ids].set(part.astype(full.dtype))
 
     return dict(cache, k=write(cache["k"], k), v=write(cache["v"], v))
@@ -267,11 +270,12 @@ def scatter_prompt_blocks(
 def pool_bytes_per_device(cache: Any) -> int:
     """Bytes of KV pool resident on EACH device.
 
-    Under tensor-parallel serving the pool shards along the KV-head dim, so
-    every device holds ``1/tp`` of each leaf; ``Sharding.shard_shape`` gives
-    the per-device shard shape for sharded and single-device placements
-    alike, which makes this the bench/stats primitive for the ``1/tp``
-    KV-bytes claim (see benchmarks/serve_tp.py)."""
+    Under tensor-parallel serving the pool shards its head-major last dim
+    (whole KV heads), so every device holds ``1/tp`` of each leaf;
+    ``Sharding.shard_shape`` gives the per-device shard shape for sharded
+    and single-device placements alike, which makes this the bench/stats
+    primitive for the ``1/tp`` KV-bytes claim (see
+    benchmarks/serve_tp.py)."""
     total = 0
     for leaf in jax.tree.leaves(cache):
         shard = leaf.sharding.shard_shape(leaf.shape)
@@ -284,7 +288,8 @@ def copy_block(cache: Any, src: jax.Array, dst: jax.Array) -> Any:
     — the copy-on-write fork primitive.  Both indices are *traced* scalars,
     so ONE compiled program forks any (src, dst) pair; ``dst`` is always a
     freshly acquired (valid) block id, so the clamping semantics of
-    ``dynamic_update_slice`` never engage."""
+    ``dynamic_update_slice`` never engage.  Every layer's block moves at
+    once, whole rows of ``Hkv * hd`` lanes."""
 
     def cp(full):
         row = jax.lax.dynamic_slice_in_dim(full, src, 1, axis=1)

@@ -143,6 +143,7 @@ from repro.models.transformer import (
     paged_verify_step,
 )
 from repro.parallel.sharding import constrain as _sh_constrain
+from repro.parallel.sharding import mesh_axis_size as _mesh_axis_size
 from repro.serve import cache as C
 from repro.serve.engine import (
     EXECUTION_MODES,
@@ -197,15 +198,17 @@ def _resolve_cache_donation() -> Tuple[str, ...]:
     return ("cache",) if jax.default_backend() != "cpu" else ()
 
 
-def _pin_pool(cache):
+def _pin_pool(cache, n_kv: int):
     """Pin the paged pool's placement at program outputs: block contents
-    shard along the KV-head dim over ``"model"`` (matching ``cache_pspecs
-    (layout="paged")``).  Without the pin, jit is free to pick a different
-    output sharding than the input's, and the NEXT dispatch of the same
-    program would see changed operand placements — one recompile per flip.
-    ``constrain`` degrades to a no-op off-mesh and drops the axis when the
-    head count does not divide it, so single-device serving is untouched."""
-    spec = (None, None, None, "model", None)
+    shard the head-major last dim over ``"model"`` when ``n_kv`` (the
+    config's KV head count) divides it, and replicate otherwise — exactly
+    ``cache_pspecs(layout="paged")``.  Without the pin, jit is free to pick
+    a different output sharding than the input's, and the NEXT dispatch of
+    the same program would see changed operand placements — one recompile
+    per flip.  ``constrain`` degrades to a no-op off-mesh, so single-device
+    serving is untouched."""
+    ax = "model" if n_kv % _mesh_axis_size("model") == 0 else None
+    spec = (None, None, None, ax)
     return dict(
         cache,
         k=_sh_constrain(cache["k"], spec),
@@ -306,7 +309,7 @@ def _decode_tick(
     carry = (cache, last_token, cur_len, jnp.zeros_like(active))
     (cache, last_token, _, _), toks = jax.lax.scan(one, carry, None, length=steps)
     if tables is not None:
-        cache = _pin_pool(cache)
+        cache = _pin_pool(cache, cfg.num_kv_heads)
     # only the sampled tokens (and the tiny carry) replicate back to the
     # host loop — logits/activations stay sharded inside the program
     toks = _sh_constrain(toks, (None, None))
@@ -424,7 +427,7 @@ def _spec_tick(
     cur_len = jnp.where(
         active, jnp.minimum(cur_len + n_acc, max_pos), cur_len
     )
-    cache = _pin_pool(cache)
+    cache = _pin_pool(cache, cfg.num_kv_heads)
     toks = _sh_constrain(toks.T, (None, None))
     n_acc = _sh_constrain(n_acc, (None,))
     last_token = _sh_constrain(last_token, (None,))
@@ -580,7 +583,10 @@ def _admit_fused_paged(
     last = jnp.take_along_axis(
         logits, (prompt_lens - 1)[:, None, None], axis=1
     )[:, 0, :]
-    cache = _pin_pool(C.scatter_prompt_blocks(cache, kvs, block_ids, block_size))
+    cache = _pin_pool(
+        C.scatter_prompt_blocks(cache, kvs, block_ids, block_size),
+        cfg.num_kv_heads,
+    )
     req_keys = _request_keys(base_key, req_ids)
     tok0s = _sh_constrain(
         _first_tokens(last, req_keys, prompt_lens, sampling), (None,)
@@ -630,7 +636,7 @@ def _prefill_chunk(
     last = jnp.take_along_axis(
         logits, (chunk_lens - 1)[:, None, None], axis=1
     )[:, 0, :]
-    cache = _pin_pool(cache)
+    cache = _pin_pool(cache, cfg.num_kv_heads)
     req_keys = _request_keys(base_key, req_ids)
     tok0s = _sh_constrain(
         _first_tokens(last, req_keys, starts + chunk_lens, sampling), (None,)
@@ -653,17 +659,18 @@ _evict_jit = _LazyJit(lambda: jax.jit(
 ))
 
 
-def _copy_block(cache, src: jax.Array, dst: jax.Array):
+def _copy_block(cache, src: jax.Array, dst: jax.Array, *, n_kv: int):
     """Copy-on-write fork (see ``cache.copy_block``): src/dst are traced, so
     one compiled program forks any block pair; warmed by ``warmup()`` when
     prefix sharing is on so the first real fork never compiles.  The copy is
     head-local under TP (each shard copies its own Hkv/tp slice), so the
     pool pin adds no traffic."""
-    return _pin_pool(C.copy_block(cache, src, dst))
+    return _pin_pool(C.copy_block(cache, src, dst), n_kv)
 
 
 _copy_block_jit = _LazyJit(lambda: jax.jit(
-    _copy_block, donate_argnames=_resolve_cache_donation(),
+    _copy_block, static_argnames=("n_kv",),
+    donate_argnames=_resolve_cache_donation(),
 ))
 
 
@@ -712,7 +719,9 @@ _spec_merge_len_jit = _LazyJit(lambda: jax.jit(_spec_merge_len))
 _pin_carry_jit = _LazyJit(
     lambda: jax.jit(lambda x: _sh_constrain(x, (None,) * x.ndim))
 )
-_pin_pool_jit = _LazyJit(lambda: jax.jit(_pin_pool))
+_pin_pool_jit = _LazyJit(
+    lambda: jax.jit(_pin_pool, static_argnames=("n_kv",))
+)
 
 
 def _jit_cache_size(fn) -> int:
@@ -1931,7 +1940,9 @@ class ServeSession:
         if self.blocks.refcount(b) <= 1:
             return                          # sole owner: write in place
         nb = self._acquire_block(slot)
-        self.cache = _copy_block_jit(self.cache, np.int32(b), np.int32(nb))
+        self.cache = _copy_block_jit(
+            self.cache, np.int32(b), np.int32(nb), n_kv=self.cfg.num_kv_heads
+        )
         self.blocks.release(b)              # this row's shared reference
         held[idx] = nb
         self._tables[slot, idx] = nb
@@ -3322,7 +3333,7 @@ class ServeSession:
         if self.mesh is not None:
             # normalize placements (see _pin_carry_jit): every later warmup
             # and serving dispatch then sees identical operand shardings
-            self.cache = _pin_pool_jit(self.cache)
+            self.cache = _pin_pool_jit(self.cache, n_kv=self.cfg.num_kv_heads)
             self._lt_dev = _pin_carry_jit(self._lt_dev)
             self._sk_dev = _pin_carry_jit(self._sk_dev)
             self._cl_dev = _pin_carry_jit(self._cl_dev)
@@ -3455,7 +3466,9 @@ class ServeSession:
             # copy-on-write fork program: src == dst makes the warmup copy a
             # content no-op; src/dst are traced, so this one compile serves
             # every real fork
-            self.cache = _copy_block_jit(self.cache, np.int32(0), np.int32(0))
+            self.cache = _copy_block_jit(
+                self.cache, np.int32(0), np.int32(0), n_kv=self.cfg.num_kv_heads
+            )
             jax.block_until_ready(self.cache)
         if self.zero_on_evict:
             self.cache = _evict_jit(self.cache, np.int32(0))

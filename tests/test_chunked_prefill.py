@@ -113,9 +113,10 @@ def test_sentinel_tail_table_reads_as_truncated_context():
     rng = np.random.default_rng(3)
     d, hq, hkv, hd, bs, w, nb, b = 32, 2, 1, 16, 4, 6, 16, 2
     p = init_attn(jax.random.PRNGKey(1), d, hq, hkv, hd)
-    k_blocks = jnp.asarray(rng.standard_normal((nb + 1, bs, hkv, hd)),
+    # a one-layer pool, (L, blocks, block_size, Hkv * hd)
+    k_blocks = jnp.asarray(rng.standard_normal((1, nb + 1, bs, hkv * hd)),
                            jnp.float32)
-    v_blocks = jnp.asarray(rng.standard_normal((nb + 1, bs, hkv, hd)),
+    v_blocks = jnp.asarray(rng.standard_normal((1, nb + 1, bs, hkv * hd)),
                            jnp.float32)
     x = jnp.asarray(rng.standard_normal((b, 1, d)), jnp.float32)
     # cursors: mid-block, block boundary (== chunk == block_size), deeper
@@ -132,7 +133,7 @@ def test_sentinel_tail_table_reads_as_truncated_context():
         for impl in ("gather", "pallas"):
             for name, table in (("tail", tail), ("real", real)):
                 o, (kb, vb) = paged_decode_attention(
-                    x, p, k_blocks, v_blocks, jnp.asarray(table),
+                    x, p, k_blocks, v_blocks, jnp.int32(0), jnp.asarray(table),
                     jnp.asarray(cur_len), block_size=bs, n_heads=hq,
                     n_kv=hkv, cfg=cfg.approx, attn_impl=impl,
                 )

@@ -156,20 +156,22 @@ def test_select_blocks_invariants(m, n, k, seed):
 # ---------------------------------------------------------------------------
 
 
-def _paged_case(rng, B, W, bs, n_kv, g, hd, *, holes=False):
+def _paged_case(rng, B, W, bs, n_kv, g, hd, *, holes=False, n_layers=1, layer=0):
     """Random paged decode-attention inputs: each row holds a random number
     of distinct blocks (possibly zero — an inactive all-sentinel row), its
     ``cur_len`` lands anywhere in the last allocated block (including offset
     0, the fresh-boundary case), and with ``holes`` an allocated middle
     block is knocked back to the sentinel — the predicate-skip case the
-    clamp-gather path never sees."""
+    clamp-gather path never sees.  The pools are whole, ``(n_layers,
+    num_blocks, bs, Hkv * hd)``, and ``layer`` is the one attended."""
     H = n_kv * g
     num_blocks = B * W + 1                       # at least one spare block
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
     kn = jnp.asarray(rng.normal(size=(B, n_kv, hd)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(B, n_kv, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(num_blocks, bs, n_kv, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(num_blocks, bs, n_kv, hd)), jnp.float32)
+    pool = (n_layers, num_blocks, bs, n_kv * hd)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.float32)
     tbl = np.full((B, W), num_blocks, np.int32)
     cur = np.zeros((B,), np.int32)
     free = list(rng.permutation(num_blocks))
@@ -182,7 +184,7 @@ def _paged_case(rng, B, W, bs, n_kv, g, hd, *, holes=False):
                 tbl[b, int(rng.integers(0, n_alloc - 1))] = num_blocks
         else:
             cur[b] = int(rng.integers(0, W * bs))   # inactive row
-    return q, kn, vn, kp, vp, jnp.asarray(tbl), jnp.asarray(cur)
+    return q, kn, vn, kp, vp, jnp.asarray(tbl), jnp.asarray(cur), jnp.int32(layer)
 
 
 def _check_paged(args, bs):
@@ -201,11 +203,37 @@ def _check_paged(args, bs):
     st.integers(1, 2),                       # Hkv
     st.integers(1, 3),                       # GQA group (H = Hkv * g)
     st.sampled_from([4, 16]),                # head_dim
+    st.integers(1, 3),                       # pool layers
     st.integers(0, 2**31 - 1),               # data seed
 )
-def test_paged_attention_matches_ref_random_tables(B, W, bs, n_kv, g, hd, seed):
+def test_paged_attention_matches_ref_random_tables(B, W, bs, n_kv, g, hd, n_layers, seed):
     rng = np.random.default_rng(seed)
-    _check_paged(_paged_case(rng, B, W, bs, n_kv, g, hd), bs)
+    layer = seed % n_layers
+    _check_paged(
+        _paged_case(rng, B, W, bs, n_kv, g, hd, n_layers=n_layers, layer=layer), bs
+    )
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+def test_paged_attention_reads_only_its_layer(layer):
+    """The kernel attends pool layer ``layer`` and no other: every other
+    layer holds NaN, which any read of it would carry into the output, and
+    the result equals the oracle over a one-layer pool holding just that
+    layer."""
+    rng = np.random.default_rng(10 + layer)
+    q, kn, vn, kp, vp, tbl, cur, lyr = _paged_case(
+        rng, 3, 3, 4, 2, 2, 8, n_layers=3, layer=layer
+    )
+    others = np.arange(3) != layer
+    kp_nan = jnp.where(others[:, None, None, None], jnp.nan, kp)
+    vp_nan = jnp.where(others[:, None, None, None], jnp.nan, vp)
+    out = np.asarray(paged_attention_pallas(
+        q, kn, vn, kp_nan, vp_nan, tbl, cur, lyr, block_size=4))
+    assert np.isfinite(out).all()
+    ref = np.asarray(paged_attention_ref(
+        q, kn, vn, kp[layer:layer + 1], vp[layer:layer + 1], tbl, cur, 0,
+        block_size=4))
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 
 @settings(max_examples=8, deadline=None)
@@ -228,9 +256,10 @@ def test_paged_attention_inactive_rows_are_exact_zero():
     """All-sentinel rows (empty decode slots) flush exactly 0.0 — no NaNs
     from the 0/0 normalizer, no garbage from the clamped DMA."""
     rng = np.random.default_rng(0)
-    q, kn, vn, kp, vp, tbl, cur = _paged_case(rng, 3, 2, 4, 2, 2, 8)
-    tbl = jnp.full_like(tbl, kp.shape[0])    # every row inactive
-    out = np.asarray(paged_attention_pallas(q, kn, vn, kp, vp, tbl, cur, block_size=4))
+    q, kn, vn, kp, vp, tbl, cur, lyr = _paged_case(rng, 3, 2, 4, 2, 2, 8)
+    tbl = jnp.full_like(tbl, kp.shape[1])    # every row inactive
+    out = np.asarray(
+        paged_attention_pallas(q, kn, vn, kp, vp, tbl, cur, lyr, block_size=4))
     assert np.array_equal(out, np.zeros_like(out))
 
 
@@ -239,9 +268,9 @@ def test_paged_attention_past_table_cur_len():
     garbage regime) still produce finite outputs that agree with the
     oracle: the fused append simply never lands."""
     rng = np.random.default_rng(1)
-    q, kn, vn, kp, vp, tbl, cur = _paged_case(rng, 2, 2, 4, 1, 2, 8)
+    q, kn, vn, kp, vp, tbl, cur, lyr = _paged_case(rng, 2, 2, 4, 1, 2, 8)
     cur = jnp.asarray([2 * 4 + 3, 2 * 4], jnp.int32)    # both past the table
-    args = (q, kn, vn, kp, vp, tbl, cur)
+    args = (q, kn, vn, kp, vp, tbl, cur, lyr)
     _check_paged(args, 4)
     assert np.isfinite(np.asarray(paged_attention_pallas(*args, block_size=4))).all()
 
@@ -249,12 +278,15 @@ def test_paged_attention_past_table_cur_len():
 def test_paged_attention_ops_validation():
     """Shape mistakes fail loudly in the wrapper, not deep in pallas."""
     rng = np.random.default_rng(0)
-    q, kn, vn, kp, vp, tbl, cur = _paged_case(rng, 2, 2, 4, 2, 2, 8)
+    q, kn, vn, kp, vp, tbl, cur, lyr = _paged_case(rng, 2, 2, 4, 2, 2, 8)
     with pytest.raises(ValueError, match="block_size"):
-        paged_attention_pallas(q, kn, vn, kp, vp, tbl, cur, block_size=8)
+        paged_attention_pallas(q, kn, vn, kp, vp, tbl, cur, lyr, block_size=8)
     with pytest.raises(ValueError, match="new-token"):
-        paged_attention_pallas(q, kn[:1], vn, kp, vp, tbl, cur, block_size=4)
+        paged_attention_pallas(q, kn[:1], vn, kp, vp, tbl, cur, lyr, block_size=4)
     with pytest.raises(ValueError, match="batch"):
-        paged_attention_pallas(q, kn, vn, kp, vp, tbl[:1], cur, block_size=4)
+        paged_attention_pallas(q, kn, vn, kp, vp, tbl[:1], cur, lyr, block_size=4)
     with pytest.raises(ValueError, match="incompatible"):
-        paged_attention_pallas(q[:, :3], kn, vn, kp, vp, tbl, cur, block_size=4)
+        paged_attention_pallas(q[:, :3], kn, vn, kp, vp, tbl, cur, lyr, block_size=4)
+    # a per-layer pool (the layout before the whole pool) is refused
+    with pytest.raises(ValueError, match="pool must be"):
+        paged_attention_pallas(q, kn, vn, kp[0], vp[0], tbl, cur, lyr, block_size=4)

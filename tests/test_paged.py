@@ -172,7 +172,7 @@ def test_attn_impl_validation_names_choices():
 
     with pytest.raises(ValueError, match="attn_impl"):
         paged_decode_attention(
-            None, None, np.zeros((1, 1, 1, 1)), None, None, None,
+            None, None, np.zeros((1, 1, 1, 1)), None, 0, None, None,
             block_size=1, n_heads=1, n_kv=1, cfg=cfg.approx,
             attn_impl="bogus",
         )

@@ -142,9 +142,9 @@ def test_constrain_and_cache_pspecs_subprocess():
             y = f(jnp.zeros((8, 4, 8)))
             out["batch_pair"] = spec_of(y) == (("pod", "data"), None, "model")
 
-        # paged cache_pspecs: k/v shard dim 3 (Hkv) over "model"; block
-        # tables / metadata and the block dim stay replicated (trailing
-        # replicated dims normalize away, as in jit outputs)
+        # paged cache_pspecs: k/v (L, blocks, block_size, Hkv*hd) shard
+        # their head-major last dim over "model"; block tables / metadata
+        # and the block dim stay replicated
         cfg = dataclasses.replace(
             reduced_config(get_config("granite-3-2b")),
             num_layers=2, num_heads=4, num_kv_heads=4, head_dim=16,
@@ -162,6 +162,30 @@ def test_constrain_and_cache_pspecs_subprocess():
         out["paged_fallback"] = all(
             ax is None for ax in sh3["k"].spec) and all(
             ax is None for ax in sh3["v"].spec)
+
+        # Hkv = 2 does not divide tp = 4 while the merged width 2 x 256 =
+        # 512 does: sharding 512 lanes four ways would split heads, so the
+        # pool replicates
+        cfg2 = dataclasses.replace(cfg, num_heads=2, num_kv_heads=2, head_dim=256)
+        cache2 = init_paged_cache(cfg2, num_blocks=16, block_size=8)
+        out["merged_512"] = cache2["k"].shape[-1] == 512
+        sh2 = cache_pspecs(cfg2, mesh, cache2, layout="paged")
+        out["paged_split_heads_refused"] = all(
+            ax is None for ax in sh2["k"].spec) and all(
+            ax is None for ax in sh2["v"].spec)
+
+        # the scheduler's output pin agrees with cache_pspecs in both cases
+        from repro.serve.scheduler import _pin_pool
+        with jax.set_mesh(mesh):
+            for name, c, n_kv, want in (
+                ("pin_kv", cache, cfg.num_kv_heads, "model"),
+                ("pin_split_heads_refused", cache2, cfg2.num_kv_heads, None),
+            ):
+                pinned = jax.jit(lambda t: _pin_pool(t, n_kv))(c)
+                specs = [spec_of(pinned[kv]) or () for kv in ("k", "v")]
+                out[name] = all(
+                    sp == (None, None, None, "model") if want
+                    else all(ax is None for ax in sp) for sp in specs)
         print(json.dumps(out))
         """
     )
