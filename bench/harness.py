@@ -5,6 +5,23 @@ and a traffic mix (``traffic/<mix>.json``); a mix names its generator by its
 ``kind`` (``traffic/gen_<kind>.py``); a per-layer metric is a reader
 ``metrics/<metric>.py``; a kernel's cost is ``kernels/<kernel>.py``. Adding any
 of them is adding a file: nothing here lists them.
+
+A configuration may name its plain reference, ``"reference": "<name>"``:
+``references/<name>.py`` (default: ``reference.py``, the dense pre-norm GQA
+decoder). That module describes the architecture to the harness, so that an
+architecture other than the dense decoder joins by adding files:
+
+- ``served_logits(conf, key, requests, T, S, precision)``: the reference's
+  logits ``(n, vocab)`` float32 at every served position of ``requests =
+  [(prompt, served_tokens), ...]`` and the ``n`` tokens served there, within
+  a budget of ``T`` positions and ``S`` served tokens; ``precision`` is
+  ``"reference"`` or ``"control"`` (the next lower precision).
+- ``model_flops(conf, start, stop)``: the forward FLOPs of positions
+  ``start..stop-1`` of one request, which ``mfu`` counts.
+- ``REHEARSAL``: the self-tests' tiny sizes (``run.rehearsal``), architecture
+  keys and an ``as_run`` to merge.
+- ``ARCH_KEYS``: its own architecture keys, each to the program's
+  ``ModelConfig`` attribute, checked beside this module's ``ARCH_KEYS``.
 """
 from __future__ import annotations
 
@@ -56,6 +73,19 @@ def metric_readers(names, root: pathlib.Path = BENCH) -> dict:
 
 def kernel_costs(root: pathlib.Path = BENCH) -> dict:
     return {p.stem: load_module(p) for p in sorted((root / "kernels").glob("*.py"))}
+
+
+_REFERENCES = {}
+
+
+def reference_module(conf: dict, root: pathlib.Path = BENCH) -> types.ModuleType:
+    """The configuration's plain reference, loaded once per process so that
+    its compiled programs are kept between runs."""
+    path = (root / "references" / f"{conf['reference']}.py"
+            if "reference" in conf else root / "reference.py")
+    if path not in _REFERENCES:
+        _REFERENCES[path] = load_module(path)
+    return _REFERENCES[path]
 
 
 def benchmark(root: pathlib.Path = BENCH) -> dict:
@@ -116,7 +146,12 @@ ARCH_KEYS = {
 }
 
 
-def model_config(prog, conf: dict):
+def arch_keys(conf: dict, root: pathlib.Path = BENCH) -> dict:
+    """``ARCH_KEYS`` and the configuration's reference module's own."""
+    return {**ARCH_KEYS, **reference_module(conf, root).ARCH_KEYS}
+
+
+def model_config(prog, conf: dict, root: pathlib.Path = BENCH):
     """The program's model config for a configuration file, checked against
     the file's architecture so that the two cannot drift apart."""
     cfg = dataclasses.replace(
@@ -124,7 +159,7 @@ def model_config(prog, conf: dict):
         approx=prog.resolve_execution_mode(conf["mode"], conf["multiplier"]))
     if conf.get("program_overrides"):
         cfg = dataclasses.replace(cfg, **conf["program_overrides"])
-    for ours, theirs in ARCH_KEYS.items():
+    for ours, theirs in arch_keys(conf, root).items():
         if getattr(cfg, theirs) != conf[ours]:
             raise ValueError(f"{conf['model']}: the program has {theirs}="
                              f"{getattr(cfg, theirs)}, the configuration file "

@@ -49,35 +49,23 @@ def kernel_roofline(rec, kernel: str):
     return 100.0 * least / dur
 
 
-def matmul_params(cfg) -> int:
-    d, hd = cfg.d_model, cfg.head_dim
-    attn = d * cfg.num_heads * hd * 2 + d * cfg.num_kv_heads * hd * 2
-    return cfg.num_layers * (attn + 3 * d * cfg.d_ff) + d * cfg.vocab_size
-
-
-def model_flops(cfg, start: int, stop: int) -> float:
-    """Forward FLOPs of positions ``start..stop-1`` of a request: 2 x matmul
-    parameters, plus 4 L H hd x the context each position attends."""
-    n = stop - start
-    ctx = (start + 1 + stop) * n / 2          # sum of (p + 1) over the span
-    return (2.0 * matmul_params(cfg) * n
-            + 4.0 * cfg.num_layers * cfg.num_heads * cfg.head_dim * ctx)
-
-
 def mfu(rec):
     """Model FLOPs of the prompt tokens admitted and the output tokens
-    delivered in the window, over the window times the bf16 peak, in %."""
+    delivered in the window, over the window times the bf16 peak, in %; the
+    FLOPs of a span of positions are the configuration's reference module's
+    ``model_flops``."""
     if rec.peaks is None:
         return None
+    model_flops, conf = rec.reference.model_flops, rec.conf
     flops = 0.0
     for r in rec.reqs.values():
         if r["admit"] is not None and rec.t_open <= r["admit"] < rec.t_close:
-            flops += model_flops(rec.cfg, 0, r["plen"])
+            flops += model_flops(conf, 0, r["plen"])
         # output token j > 0 comes from the decode position plen + j - 1
         # (token 0 is the prefill's, counted with the prompt)
         for j, t in enumerate(r["times"]):
             if j and rec.t_open <= t < rec.t_close:
                 p = r["plen"] + j - 1
-                flops += model_flops(rec.cfg, p, p + 1)
+                flops += model_flops(conf, p, p + 1)
     window = rec.t_close - rec.t_open
     return 100.0 * flops / (window * rec.peaks["bf16_flops_per_s"])

@@ -25,6 +25,10 @@ of those dots is exact in bf16 and every product exact in f32.
 
 Several requests are packed into one sequence of fixed length with segment
 ids (one compiled shape per cell); attention never crosses a segment.
+
+This is the reference of every configuration that names no other
+(``harness.reference_module``): ``served_logits``, ``model_flops``,
+``REHEARSAL`` and ``ARCH_KEYS`` are the interface the harness reads.
 """
 from __future__ import annotations
 
@@ -36,6 +40,14 @@ import jax.numpy as jnp
 import numpy as np
 
 HI = jax.lax.Precision.HIGHEST
+
+# the self-tests' tiny sizes: a two-layer decoder of width 128
+REHEARSAL = {
+    "num_hidden_layers": 2, "hidden_size": 128, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 32, "intermediate_size": 256,
+    "vocab_size": 512, "as_run": {"padded_vocab_size": 512}}
+# no architecture keys beyond ``harness.ARCH_KEYS``
+ARCH_KEYS = {}
 
 # MUL3x3_2 minus the exact product, at the only entries where they differ
 # (Table III, with (7,6) read from its output bits as 46)
@@ -285,7 +297,32 @@ def pack(requests, T: int, S: int) -> tuple:
             np.asarray(served, np.int64))
 
 
-def gaps(lg: np.ndarray, tokens: np.ndarray) -> np.ndarray:
-    """How far each chosen token's reference logit lies below the best."""
-    lg = np.asarray(lg, np.float64)
-    return lg.max(axis=1) - lg[np.arange(len(tokens)), tokens]
+def served_logits(conf: dict, key, requests, T: int, S: int,
+                  precision: str = "reference") -> tuple:
+    """The reference's logits (n, vocab) at every served position of
+    ``requests = [(prompt, served_tokens), ...]``, packed into ``T`` positions
+    and ``S`` served tokens, and the n tokens served there."""
+    packed, served = pack(requests, T, S)
+    keep = served >= 0
+    return np.asarray(logits(arch_of(conf), key, packed, precision))[keep], served[keep]
+
+
+# -- the FLOP count --------------------------------------------------------------
+
+
+def matmul_params(conf: dict) -> int:
+    d, hd = conf["hidden_size"], conf["head_dim"]
+    attn = (d * conf["num_attention_heads"] * hd * 2
+            + d * conf["num_key_value_heads"] * hd * 2)
+    return (conf["num_hidden_layers"] * (attn + 3 * d * conf["intermediate_size"])
+            + d * conf["vocab_size"])
+
+
+def model_flops(conf: dict, start: int, stop: int) -> float:
+    """Forward FLOPs of positions ``start..stop-1`` of a request: 2 x matmul
+    parameters, plus 4 L H hd x the context each position attends."""
+    n = stop - start
+    ctx = (start + 1 + stop) * n / 2          # sum of (p + 1) over the span
+    return (2.0 * matmul_params(conf) * n
+            + 4.0 * conf["num_hidden_layers"] * conf["num_attention_heads"]
+            * conf["head_dim"] * ctx)

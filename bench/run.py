@@ -14,9 +14,10 @@
    handed it to the host. Chat cells then drain the requests sent in the
    window, with arrivals still coming, before the session is closed.
 4. ``correct``: a sample of the finished requests, drawn from the seed and
-   holding the longest, goes through the plain reference (``reference.py``)
-   once the session is freed. Each served token's gap is how far its
-   reference logit lies below the reference's best; the widest gap
+   holding the longest, goes through the configuration's plain reference
+   (``reference.py`` unless it names another) once the session is freed.
+   Each served token's gap is how far its reference logit lies below the
+   reference's best; the widest gap
    (``served_logit_gap``) and the mean (``mean_served_logit_gap``) are
    compared with the limits in the cell's file, where it sets one. Every
    finished request must hold its ``max_new`` tokens, all in the
@@ -249,33 +250,38 @@ def sample(reqs, seed: int, T: int = REF_T, S: int = REF_S) -> list:
     return out
 
 
-def reference_gap(jax, conf: dict, seed: int, picked: list,
+def gaps(lg, tokens):
+    """How far each chosen token's reference logit lies below the best."""
+    import numpy as np
+    lg = np.asarray(lg, np.float64)
+    return lg.max(axis=1) - lg[np.arange(len(tokens)), tokens]
+
+
+def reference_gap(ref, conf: dict, seed: int, picked: list,
                   precision: str = "reference") -> dict:
     """How far each served token's reference logit lies below the
     reference's best, over the picked requests: the widest gap and the mean;
     with ``precision="control"`` the same of the tokens the control ranks
     first, under ``control_``. Beside them, how many distinct tokens were
     served and the reference's median margin between its two best logits,
-    which say how far a wrong token would read."""
+    which say how far a wrong token would read. ``ref`` is the
+    configuration's reference module (``harness.reference_module``)."""
     import numpy as np
-    import reference as ref
-    a = ref.arch_of(conf)
     key = harness.prng_key(seed)
-    packed, served = ref.pack([(r["prompt"], r["tokens"]) for r in picked],
-                              REF_T, REF_S)
-    keep = served >= 0
-    lg = np.asarray(ref.logits(a, key, packed, "reference"))[keep]
-    tokens = served[keep]
-    g = ref.gaps(lg, tokens)
+    requests = [(r["prompt"], r["tokens"]) for r in picked]
+    lg, tokens = ref.served_logits(conf, key, requests, REF_T, REF_S,
+                                   "reference")
+    lg = np.asarray(lg)
+    g = gaps(lg, tokens)
     top2 = np.sort(np.asarray(lg, np.float64), axis=1)[:, -2:]
-    out = {"tokens": int(keep.sum()), "served_logit_gap": float(g.max()),
+    out = {"tokens": len(tokens), "served_logit_gap": float(g.max()),
            "mean_served_logit_gap": float(g.mean()),
            "argmax_agree": float(np.mean(lg.argmax(1) == tokens)),
            "distinct_tokens": int(np.unique(tokens).size),
            "median_top2_margin": float(np.median(top2[:, 1] - top2[:, 0]))}
     if precision == "control":
-        ctrl = np.asarray(ref.logits(a, key, packed, "control"))[keep]
-        gc_ = ref.gaps(lg, ctrl.argmax(1))
+        ctrl, _ = ref.served_logits(conf, key, requests, REF_T, REF_S, "control")
+        gc_ = gaps(lg, np.asarray(ctrl).argmax(1))
         out["control_served_logit_gap"] = float(gc_.max())
         out["control_mean_served_logit_gap"] = float(gc_.mean())
     return out
@@ -290,14 +296,21 @@ def within(read: dict, limits: dict) -> bool:
 # -- the run -------------------------------------------------------------------
 
 
-def rehearsal(conf: dict, mix: dict) -> tuple:
+def rehearsal(conf: dict, mix: dict, root=harness.BENCH) -> tuple:
     """A tiny model and session for the self-tests, with the traffic clipped
-    to fit it; every other part of the run is the cell's own."""
-    tiny = {"num_hidden_layers": 2, "hidden_size": 128, "num_attention_heads": 4,
-            "num_key_value_heads": 2, "head_dim": 32, "intermediate_size": 256,
-            "vocab_size": 512}
-    conf = dict(conf, **tiny, as_run=dict(conf["as_run"], padded_vocab_size=512))
-    conf["program_overrides"] = {harness.ARCH_KEYS[k]: v for k, v in tiny.items()}
+    to fit it; every other part of the run is the cell's own. The sizes are
+    the reference module's ``REHEARSAL``: each key is an architecture key
+    (``harness.arch_keys``), and goes to the program too, or ``as_run``."""
+    tiny = dict(harness.reference_module(conf, root).REHEARSAL)
+    as_run = tiny.pop("as_run", {})
+    keys = harness.arch_keys(conf, root)
+    unmapped = sorted(set(tiny) - set(keys))
+    if unmapped:
+        raise ValueError(f"{conf['name']}: the rehearsal keys {unmapped} map "
+                         "to no program attribute (harness.arch_keys)")
+    conf = {**conf, **tiny, "as_run": {**conf["as_run"], **as_run}}
+    conf["program_overrides"] = {**conf.get("program_overrides", {}),
+                                 **{keys[k]: v for k, v in tiny.items()}}
     conf["session"] = dict(conf["session"], num_slots=4, max_len=96,
                            prompt_buckets=[32, 64])
     mix = dict(mix, ramp_s=0.5, drain_cap_s=60.0,
@@ -328,7 +341,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     bench = harness.benchmark(root)
     rate = rate or cell.get("rate_rps")
     if rehearse:
-        conf, mix = rehearsal(conf, mix)
+        conf, mix = rehearsal(conf, mix, root)
         rate = rate and 20.0
     device_check(jax, cell["chips"], rehearse)
     dev = device_info(jax)
@@ -350,7 +363,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     print(f"bench: {cell_name} seed {seed} on {dev}; compile cache {cache_dir}",
           file=sys.stderr, flush=True)
 
-    cfg = harness.model_config(prog, dict(conf, mode=program_mode or conf["mode"]))
+    cfg = harness.model_config(prog, dict(conf, mode=program_mode or conf["mode"]),
+                              root)
     key = harness.prng_key(seed)
     params = jax.jit(lambda k: harness.make_weights(prog, cfg, k))(key)
     jax.block_until_ready(params)
@@ -405,11 +419,12 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
                         if drv.reqs[rid]["tokens"] is None])
 
     metrics, attempted, failed = end_to_end(drv, t_open, t_close, setup_s)
+    ref = harness.reference_module(conf, root)
     layer = {}
     if trace:
         tr.load()
         rec = RunRecord(conf, cfg, mix, drv, t_open, t_close, stats0, stats1,
-                        tr, peaks, harness.kernel_costs(root))
+                        tr, peaks, harness.kernel_costs(root), ref)
         names = [m["name"] for m in harness.cell_metrics(bench, cell_name, True)]
         for name, mod in harness.metric_readers(names, root).items():
             v = mod.read(rec)
@@ -427,7 +442,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
         return {"metrics": metrics, "attempted": attempted, "failed": failed,
                 "queue": [(s["t_start"] - t_open, s["queued"]) for s in drv.steps],
                 "device": dict(dev, memory_peak_bytes=memory_peak)}
-    read = reference_gap(jax, conf, seed, sample(finished, seed),
+    read = reference_gap(ref, conf, seed, sample(finished, seed),
                          "control" if control else "reference")
     # with ``control``, the control's readings stand in the program's place
     judged = {n: read["control_" + n] for n in GAPS} if control else read
@@ -493,11 +508,13 @@ def _peak_bytes(jax) -> int:
 class RunRecord:
     """What a per-layer metric reader may read: the configuration, the
     client's request and step records, the session's counters at the window's
-    ends, the trace, the peaks and the kernels' cost functions."""
+    ends, the trace, the peaks, the kernels' cost functions and the
+    configuration's reference module (for its FLOP count)."""
 
     def __init__(self, conf, cfg, mix, drv, t_open, t_close, stats0, stats1,
-                 trace, peaks, kernels):
+                 trace, peaks, kernels, reference):
         self.conf, self.cfg, self.mix = conf, cfg, mix
+        self.reference = reference
         self.reqs, self.step_log = drv.reqs, drv.steps
         self.t_open, self.t_close = t_open, t_close
         self.stats0, self.stats1 = stats0, stats1

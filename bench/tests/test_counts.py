@@ -37,13 +37,26 @@ def test_paged_attention_counts():
     assert nbytes == 2 * 8000 * 8 * 64 * 2 + 8 * 32 * 64 * (2 + 4)
 
 
-def test_model_flops_per_token():
-    n = readers.matmul_params(GRANITE)
+@pytest.mark.parametrize("name", ["granite-3-2b.exact",
+                                  "granite-3-2b.approx-mul8x8_2"])
+def test_model_flops_per_token(name):
+    conf = harness.load_config(name)
+    ref = harness.reference_module(conf)
+    n = ref.matmul_params(conf)
     assert n == 40 * (2 * 2048 * 2048 + 2 * 2048 * 512 + 3 * 2048 * 8192) + 2048 * 49155
     assert n == 2_533_365_760
     # one decode position at context 1000 attends 1000 + 1 positions
-    f = readers.model_flops(GRANITE, 1000, 1001)
-    assert f == pytest.approx(2 * n + 4 * 40 * 32 * 64 * 1001)
+    assert ref.model_flops(conf, 1000, 1001) == 2 * n + 4 * 40 * 32 * 64 * 1001 \
+        == 5_394_739_200
     # a prompt of 3 positions attends 1 + 2 + 3
-    assert readers.model_flops(GRANITE, 0, 3) == pytest.approx(
-        3 * 2 * n + 4 * 40 * 32 * 64 * 6)
+    assert ref.model_flops(conf, 0, 3) == 3 * 2 * n + 4 * 40 * 32 * 64 * 6 \
+        == 15_202_160_640
+    # mfu counts the prompt admitted in the window and the output tokens
+    # delivered there after the first: positions 0-2, then 3 and 4
+    rec = types.SimpleNamespace(
+        conf=conf, reference=ref, peaks={"bf16_flops_per_s": 100.0},
+        t_open=0.0, t_close=1.0,
+        reqs={0: {"admit": 0.5, "plen": 3, "times": [0.6, 0.7, 0.8]},
+              1: {"admit": -1.0, "plen": 9, "times": [-0.5, 1.5]}})
+    assert readers.mfu(rec) == 15_202_160_640 + 5_068_042_240 + 5_068_369_920 \
+        == 25_338_572_800
