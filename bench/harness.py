@@ -22,6 +22,10 @@ architecture other than the dense decoder joins by adding files:
   keys and an ``as_run`` to merge.
 - ``ARCH_KEYS``: its own architecture keys, each to the program's
   ``ModelConfig`` attribute, checked beside this module's ``ARCH_KEYS``.
+- ``SCOPES``: the names of the program's ``jax.named_scope``s that its own
+  layers run under, read in a traced run beside ``program_trace.SCOPES``, so
+  that a reader ``metrics/<metric>.py`` can take a new layer's device time by
+  its scope, in any program.
 """
 from __future__ import annotations
 

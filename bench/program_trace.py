@@ -1,8 +1,10 @@
 """What the program itself marks in a profiler trace: its name scopes on the
 device and its ``serve.*`` spans on the host.
 
-* Device: the model's layers run under ``jax.named_scope`` (``embed``,
-  ``norm``, ``attention``, ``kv_write``, ``dense``, ``mlp``, ``sample``), which
+* Device: the model's layers run under ``jax.named_scope`` (``SCOPES``, the
+  names every family marks: ``embed``, ``norm``, ``attention``, ``kv_write``,
+  ``dense``, ``mlp``, ``sample``; a configuration's reference module adds the
+  names its architecture's own layers run under as its ``SCOPES``), which
   sits in each HLO instruction's ``op_name`` metadata. The trace carries the
   optimized HLO of every module it ran (``/host:metadata`` plane, one
   ``Hlo Proto`` stat per module, keyed by the module event's name such as
@@ -141,16 +143,24 @@ def op_names(hlo_proto: bytes) -> Dict[str, str]:
     return out
 
 
-def innermost_scope(op_name: str) -> Optional[str]:
-    """The last program scope on an ``op_name`` path, or ``None``. A scope
+def innermost_scope(op_name: str, scopes=SCOPES) -> Optional[str]:
+    """The last of ``scopes`` on an ``op_name`` path, or ``None``. A scope
     under a transformation reads ``vmap(sample)`` or ``transpose(jvp(dense))``
     there."""
     for part in reversed(op_name.split("/")):
         while part.endswith(")") and "(" in part:
             part = part[part.index("(") + 1:-1]
-        if part in SCOPES:
+        if part in scopes:
             return part
     return None
+
+
+def program_of(module: str) -> str:
+    """The program of a module event's name: ``jit__decode_tick(12)`` reads
+    ``_decode_tick``."""
+    if module.endswith(")") and "(" in module:
+        module = module[:module.rindex("(")]
+    return module[len("jit_"):] if module.startswith("jit_") else module
 
 
 def instruction(event_name: str) -> str:
@@ -164,11 +174,12 @@ def instruction(event_name: str) -> str:
 class ProgramTrace:
     """A ``tracing.TraceData`` with the program's own marks beside it:
     ``program_spans`` (host ``serve.*`` events) and, per module event name,
-    each instruction's ``op_name``."""
+    each instruction's ``op_name``, read against the run's ``scopes``."""
 
     def __init__(self, data: tracing.TraceData, program_spans: List[tracing.Event],
-                 module_ops: Dict[str, Dict[str, str]]):
+                 module_ops: Dict[str, Dict[str, str]], scopes):
         self.data = data
+        self.scopes = tuple(scopes)
         self.program_spans = sorted(
             (e for e in program_spans
              if e.start_ns >= data.lo and e.end_ns <= data.hi),
@@ -203,7 +214,7 @@ class ProgramTrace:
             if e.start_ns < m.start_ns or e.end_ns > m.end_ns:
                 continue
             scope = innermost_scope(self.module_ops[m.name].get(
-                instruction(e.name), ""))
+                instruction(e.name), ""), self.scopes)
             out[scope] = out.get(scope, 0.0) + own
         self._by_scope[program] = out
         return out
@@ -216,6 +227,10 @@ class ProgramTrace:
         if not any(s is not None for s in by):
             return None
         return by.get(scope, 0.0)
+
+    def calls(self, program: str) -> int:
+        """``program``'s module events in the trace: one per call."""
+        return sum(1 for m in self.data.modules if program in m.name)
 
     # host
     def steps(self) -> List[tracing.Event]:
@@ -252,7 +267,7 @@ class ProgramTrace:
                 for a, b in tracing.gaps(busy, d.lo, d.hi)]
 
 
-def from_bytes(xspace: bytes) -> ProgramTrace:
+def from_bytes(xspace: bytes, scopes) -> ProgramTrace:
     import jax
     pd = jax.profiler.ProfileData.from_serialized_xspace(xspace)
     spans = []
@@ -266,28 +281,30 @@ def from_bytes(xspace: bytes) -> ProgramTrace:
     wanted = {m.name for m in data.modules}
     module_ops = {name: op_names(blob)
                   for name, blob in hlo_protos(xspace).items() if name in wanted}
-    return ProgramTrace(data, spans, module_ops)
+    return ProgramTrace(data, spans, module_ops, scopes)
 
 
-def load(directory) -> ProgramTrace:
+def load(directory, scopes) -> ProgramTrace:
     path = tracing.find_xplane(directory)
     if path is None:
         raise FileNotFoundError(f"no .xplane.pb under {directory}")
     with open(path, "rb") as f:
-        return from_bytes(f.read())
+        return from_bytes(f.read(), scopes)
 
 
 # -- what the readers share ------------------------------------------------------
 
 
 def of(rec) -> Optional[ProgramTrace]:
-    """The run's program trace, read once; the first read prints the decode
-    program's time by scope and the idle gaps by program span to stderr."""
+    """The run's program trace, read once against ``SCOPES`` and the
+    configuration's reference module's own ``SCOPES``; the first read prints
+    the decode and admit programs' time by scope and the idle gaps by program
+    span to stderr."""
     if rec.trace is None:
         return None
     pt = getattr(rec, "_program_trace", None)
     if pt is None:
-        pt = load(rec.trace.dir)
+        pt = load(rec.trace.dir, SCOPES + tuple(rec.reference.SCOPES))
         rec._program_trace = pt
         report(pt, rec, sys.stderr)
     return pt
@@ -306,6 +323,20 @@ def decode_scope_ms(rec, scope: Optional[str]) -> Optional[float]:
     return 1000.0 * sec / ticks
 
 
+def program_scope_ms(rec, program: str, scope: Optional[str]) -> Optional[float]:
+    """Device ms per call of ``program``'s operations in ``scope`` (``None``:
+    in no program scope), over the traced window; a call is one of
+    ``program``'s module events."""
+    pt = of(rec)
+    if pt is None:
+        return None
+    calls = pt.calls(program)
+    sec = pt.scope_seconds(program, scope)
+    if not calls or sec is None:
+        return None
+    return 1000.0 * sec / calls
+
+
 def span_ms_per_step(rec, name: str) -> Optional[float]:
     """Self ms of the ``name`` spans per traced ``serve.step``."""
     pt = of(rec)
@@ -314,16 +345,24 @@ def span_ms_per_step(rec, name: str) -> Optional[float]:
     return 1000.0 * pt.span_self_seconds(name) / len(pt.steps())
 
 
+def _scope_line(pt: ProgramTrace, program: str, n: int, per: str, out) -> None:
+    """``program``'s device ms per ``per`` by scope, over ``n`` of them."""
+    by = {s: pt.scope_seconds(program, s) for s in pt.scopes + (None,)}
+    if by[None] is None or n <= 0:
+        return
+    module = pt.data.module_seconds(program)
+    parts = ", ".join(f"{s or 'unscoped'} {1000.0 * v / n:.3f}"
+                      for s, v in by.items())
+    print(f"program scopes, {program} ms per {per} over {n} {per}s: "
+          f"{parts}; sum {1000.0 * sum(by.values()) / n:.3f} of module "
+          f"{1000.0 * module / n:.3f}", file=out, flush=True)
+
+
 def report(pt: ProgramTrace, rec, out) -> None:
     ticks = rec.trace.counters1["ticks"] - rec.trace.counters0["ticks"]
-    by = {s: pt.scope_seconds("_decode_tick", s) for s in SCOPES + (None,)}
-    if by[None] is not None and ticks > 0:
-        module = pt.data.module_seconds("_decode_tick")
-        parts = ", ".join(f"{s or 'unscoped'} {1000.0 * v / ticks:.3f}"
-                          for s, v in by.items())
-        print(f"program scopes, _decode_tick ms per tick over {ticks} ticks: "
-              f"{parts}; sum {1000.0 * sum(by.values()) / ticks:.3f} of module "
-              f"{1000.0 * module / ticks:.3f}", file=out, flush=True)
+    _scope_line(pt, "_decode_tick", ticks, "tick", out)
+    for program in sorted({program_of(m) for m in pt.module_ops} - {"_decode_tick"}):
+        _scope_line(pt, program, pt.calls(program), "call", out)
     steps = pt.steps()
     if steps:
         parts = ", ".join(

@@ -28,7 +28,8 @@ ids (one compiled shape per cell); attention never crosses a segment.
 
 This is the reference of every configuration that names no other
 (``harness.reference_module``): ``served_logits``, ``model_flops``,
-``REHEARSAL`` and ``ARCH_KEYS`` are the interface the harness reads.
+``REHEARSAL``, ``ARCH_KEYS`` and ``SCOPES`` are the interface the harness
+reads.
 """
 from __future__ import annotations
 
@@ -41,13 +42,15 @@ import numpy as np
 
 HI = jax.lax.Precision.HIGHEST
 
-# the self-tests' tiny sizes: a two-layer decoder of width 128
+# the self-tests' tiny sizes: a four-layer decoder of width 128
 REHEARSAL = {
-    "num_hidden_layers": 2, "hidden_size": 128, "num_attention_heads": 4,
+    "num_hidden_layers": 4, "hidden_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 32, "intermediate_size": 256,
     "vocab_size": 512, "as_run": {"padded_vocab_size": 512}}
 # no architecture keys beyond ``harness.ARCH_KEYS``
 ARCH_KEYS = {}
+# no program scopes beyond ``program_trace.SCOPES``
+SCOPES = ()
 
 # MUL3x3_2 minus the exact product, at the only entries where they differ
 # (Table III, with (7,6) read from its output bits as 46)

@@ -31,7 +31,8 @@
 With ``--trace 1`` the profiler records the last ``TRACE_S`` seconds of the
 window and the metrics are the cell's per-layer ones (``metrics/<name>.py``).
 ``--rehearse`` runs a tiny model on any backend for the self-tests and
-reports no metrics.
+reports no metrics; its ramp and window are counted in session steps
+(``StepClock``), so a self-test reads the same on a fast host and a slow one.
 """
 from __future__ import annotations
 
@@ -51,6 +52,7 @@ sys.path.insert(0, HERE)
 import harness  # noqa: E402
 
 TRACE_S = 8.0        # the profiled part of a --trace 1 window
+REHEARSAL_STEPS_PER_S = 250   # a rehearsal's clock (StepClock)
 REF_T, REF_S = 4096, 1024   # reference: packed positions, served tokens
 GAPS = ("served_logit_gap", "mean_served_logit_gap")
 
@@ -82,13 +84,45 @@ def device_info(jax) -> dict:
 # -- the load client ----------------------------------------------------------
 
 
+class WallClock:
+    """The host's clock: a run's window is ``--seconds`` of wall time."""
+
+    now = staticmethod(time.perf_counter)
+
+    def tick(self):
+        pass
+
+    def sleep_until(self, t: float):
+        time.sleep(max(0.0, t - time.perf_counter()))
+
+
+class StepClock:
+    """A rehearsal's clock: it advances ``1 / steps_per_s`` with each
+    ``step()`` of the session and jumps over a wait for an arrival, so what a
+    rehearsal serves and checks is the same on a fast host and a slow one."""
+
+    def __init__(self, steps_per_s: float):
+        self.t, self.dt = 0.0, 1.0 / steps_per_s
+
+    def now(self) -> float:
+        return self.t
+
+    def tick(self):
+        self.t += self.dt
+
+    def sleep_until(self, t: float):
+        self.t = max(self.t, t)
+
+
 class Client:
     """Feeds the cell's traffic to the session and records, for every
     request, when it was due, when it was admitted, and when each of its
-    tokens reached the host."""
+    tokens reached the host, on ``clock``."""
 
-    def __init__(self, sess, stream, mix: dict, num_slots: int, span):
+    def __init__(self, sess, stream, mix: dict, num_slots: int, span,
+                 clock=None):
         self.sess, self.stream = sess, stream
+        self.clock = clock or WallClock()
         self.open_loop = mix["kind"] == "open_loop"
         self.backlog = mix.get("backlog_per_slot", 0) * num_slots
         self.span = span
@@ -145,7 +179,7 @@ class Client:
             self.live.pop(c.req_id, None)
 
     def step(self, arrivals: bool = True, t_stop: float = float("inf")):
-        now = time.perf_counter()
+        now = self.clock.now()
         self._feed(now, arrivals)
         if self.sess.drained:
             if not (self.open_loop and arrivals):
@@ -153,13 +187,13 @@ class Client:
             with self.span("bench.wait_arrival"):
                 if self.next_req is None:
                     self.next_req = next(self.stream)
-                wake = min(self.t0 + self.next_req[0], t_stop)
-                time.sleep(max(0.0, wake - time.perf_counter()))
+                self.clock.sleep_until(min(self.t0 + self.next_req[0], t_stop))
             return True
-        t_start = time.perf_counter()
+        t_start = self.clock.now()
         with self.span("bench.step"):
             finished = self.sess.step()
-        t_end = time.perf_counter()
+        self.clock.tick()
+        t_end = self.clock.now()
         self._harvest(t_start, t_end, finished)
         self.steps.append({"t_start": t_start, "t_end": t_end,
                            "queued": self.n_sub - self.sess.stats.admitted,
@@ -177,7 +211,7 @@ class Client:
                 "ctx": int(sum(int(self.sess._cur_len[i]) for i in live))}
 
     def run_until(self, t_stop, arrivals: bool = True):
-        while time.perf_counter() < t_stop:
+        while self.clock.now() < t_stop:
             self.step(arrivals, t_stop)
 
 
@@ -382,31 +416,32 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     gen = harness.generator(mix, root)
     rng = np.random.default_rng([*harness.seed_key_parts(seed), 1])
     stream = gen.stream(mix, rng, cfg.vocab_size, rate)
-    drv = Client(sess, stream, mix, num_slots, span)
+    clock = StepClock(REHEARSAL_STEPS_PER_S) if rehearse else WallClock()
+    drv = Client(sess, stream, mix, num_slots, span, clock)
     setup_s = time.perf_counter() - T_START
 
     # ramp (discarded), then the window
-    drv.t0 = time.perf_counter()
+    drv.t0 = clock.now()
     drv.run_until(drv.t0 + mix["ramp_s"])
-    stats0 = _counters(sess)
-    t_open = time.perf_counter()
+    stats0 = _counters(sess, clock)
+    t_open = clock.now()
     t_close = t_open + seconds
     tr = None
     if trace:
         # the profiler covers the window's last TRACE_S seconds, so that
         # writing the trace out falls after the window
         drv.run_until(max(t_open, t_close - TRACE_S))
-        tr = _Trace(jax, cell_name)
+        tr = _Trace(jax, cell_name, clock)
         tr.start(sess)
     drv.run_until(t_close)
     if trace:
         tr.stop(sess)
-    stats1 = _counters(sess)
+    stats1 = _counters(sess, clock)
     if drv.open_loop and check:
         # drain what the window sent, with arrivals still coming
         cap = t_close + mix["drain_cap_s"]
         with span("bench.drain"):
-            while time.perf_counter() < cap and any(
+            while clock.now() < cap and any(
                     r["tokens"] is None for r in drv.reqs.values()
                     if t_open <= r["due"] < t_close):
                 drv.step(t_stop=cap)
@@ -414,7 +449,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
                   for k, v in prog.compile_stats().items()
                   if v != compiled_at_setup.get(k, 0)}
     memory_peak = _peak_bytes(jax)
-    t = time.perf_counter()
+    t = clock.now()
     drv._harvest(t, t, [c for rid, c in sess.close().items()
                         if drv.reqs[rid]["tokens"] is None])
 
@@ -492,12 +527,12 @@ class _NullSpan:
         return False
 
 
-def _counters(sess) -> dict:
+def _counters(sess, clock) -> dict:
     s = sess.stats
     return {"wall_s": s.wall_s, "host_block_s": s.host_block_s,
             "prefill_tokens": s.prefill_tokens, "ticks": s.ticks,
             "admitted": s.admitted, "generated_tokens": s.generated_tokens,
-            "admit_calls": s.admit_calls, "t": time.perf_counter()}
+            "admit_calls": s.admit_calls, "t": clock.now()}
 
 
 def _peak_bytes(jax) -> int:
@@ -529,21 +564,21 @@ class _Trace:
     """The profiler over the last part of the window, reduced by
     ``tracing.py``."""
 
-    def __init__(self, jax, cell):
-        self.jax = jax
+    def __init__(self, jax, cell, clock):
+        self.jax, self.clock = jax, clock
         self.dir = harness.OUT / f"trace-{cell}"
 
     def start(self, sess):
         import shutil
         shutil.rmtree(self.dir, ignore_errors=True)
-        self.counters0 = _counters(sess)
+        self.counters0 = _counters(sess, self.clock)
         opts = self.jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0      # host spans only: bench.* and the runtime's
         self.jax.profiler.start_trace(str(self.dir), profiler_options=opts)
 
     def stop(self, sess):
         self.jax.block_until_ready(sess.cache)
-        self.counters1 = _counters(sess)
+        self.counters1 = _counters(sess, self.clock)
         self.jax.profiler.stop_trace()
 
     def load(self):

@@ -101,3 +101,32 @@ def test_end_to_end_from_the_records():
             for a, b in zip(r["times"], r["times"][1:])]
     assert out["itl_p99_s"][0] == pytest.approx(np.percentile(gaps, 99))
     assert out["setup_s"] == (1.5, "s")
+
+
+def test_a_rehearsal_clock_counts_steps_not_wall_time():
+    """On ``StepClock`` a step lasts 1/steps_per_s and a wait for an arrival
+    jumps to it, so a slower host serves the same steps at the same times."""
+    records = []
+    for pause in (0.0, 0.002):
+        sess = ScriptedSession()
+        step = sess.step
+
+        def slow_step(step=step, pause=pause):
+            import time
+            time.sleep(pause)
+            return step()
+        sess.step = slow_step
+        drv = run.Client(sess, stream(), {"kind": "open_loop"}, 2,
+                         lambda name: run._NullSpan(), run.StepClock(100))
+        drv.t0 = drv.clock.now()
+        drv.run_until(drv.t0 + 0.2)
+        records.append((drv.steps, drv.reqs))
+        assert all(s["t_end"] - s["t_start"] == pytest.approx(0.01)
+                   for s in drv.steps)
+        # the third request, due at 0.05, comes after the first two finished
+        assert drv.reqs[2]["admit"] == pytest.approx(0.05)
+        assert drv.clock.now() == pytest.approx(0.2)
+    (steps_a, reqs_a), (steps_b, reqs_b) = records
+    assert steps_a == steps_b
+    assert [(r["admit"], r["times"]) for r in reqs_a.values()] == [
+        (r["admit"], r["times"]) for r in reqs_b.values()]

@@ -8,7 +8,10 @@ configuration names one, else the reference in the next lower precision in
 the program's place) must read wider than the sound program too, and come
 out not correct at the cell's own limits.
 
-At this size the approx model's own gaps are wide (two layers of width 128
+The cells are ``BENCHMARK.json``'s workloads, so a cell's faults and
+control are checked here once its entry is there.
+
+At this size the approx model's own gaps are wide (four layers of width 128
 quantized to uint8 per tensor), so only the exact cells' sound runs are held
 to the chip-set limits here."""
 import pytest
@@ -17,7 +20,7 @@ import faults
 import harness
 import run
 
-CELLS = ["granite-3-2b.exact.offline", "granite-3-2b.approx.offline"]
+CELLS = [w["name"] for w in harness.benchmark()["workloads"]]
 SEED = 2**35 + 4
 
 
@@ -35,7 +38,7 @@ def test_broken_path_is_not_correct(cell, fault, restore):
     sound = run.run(cell, SEED, 2.0, False, rehearse=True)
     broken = run.run(cell, SEED, 2.0, False, rehearse=True,
                      patch=lambda prog: restore.append(faults.FAULTS[fault](prog)))
-    if cell.split(".")[1] == "exact":
+    if harness.load_config(harness.load_cell(cell)["config"])["mode"] == "exact":
         assert sound["correct"], sound["checks"]
     assert not broken["correct"], broken["checks"]
     gap = "served_logit_gap"
